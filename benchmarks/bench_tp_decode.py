@@ -12,36 +12,24 @@ Three claims under test:
      a single GH200 (144 GiB HBM) but fit at TP=8 (~94.5 GiB/chip) with
      HBM left over for a KV block pool.
 
-Needs 4 XLA devices; when jax is already up with fewer (e.g. under
-``benchmarks.run`` after other modules imported it), the bench re-execs
-itself in a subprocess with the host-device-count flag set.
+Needs 4 XLA devices. Run alone, it sets the host-device-count flag itself;
+when jax is already up with fewer (e.g. under ``benchmarks.run`` after other
+modules imported it), it stops with the recipe: export
+``XLA_FLAGS=--xla_force_host_platform_device_count=4`` before the process
+starts. It never starts a second process, which could not reach a chip
+that this one holds.
 
     PYTHONPATH=src python -m benchmarks.bench_tp_decode [--quick]
 
 CSV rows: name,seconds,derived.
 """
 import dataclasses
-import os
-import subprocess
 import sys
 import time
 
 import numpy as np
 
 NEED_DEVICES = 4
-_REEXEC_SENTINEL = "_BENCH_TP_DECODE_REEXEC"
-
-
-def _reexec_with_devices() -> None:
-    env = dict(os.environ)
-    flag = f"--xla_force_host_platform_device_count={NEED_DEVICES}"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
-    env[_REEXEC_SENTINEL] = "1"
-    env.setdefault("PYTHONPATH", "src")
-    rc = subprocess.call([sys.executable, "-m", "benchmarks.bench_tp_decode"]
-                        + sys.argv[1:], env=env)
-    if rc != 0:
-        raise RuntimeError(f"re-exec'd bench_tp_decode exited rc={rc}")
 
 
 def make_requests(cfg, n, out_len, seed=11):
@@ -74,16 +62,8 @@ def run_engine(cfg, tp, n_req, out_len):
 
 
 def main() -> None:
-    try:
-        from repro.launch.hostenv import ensure_host_devices
-        ensure_host_devices(NEED_DEVICES)
-    except RuntimeError:
-        # jax already imported with too few devices — the flag can no
-        # longer act in this process; run the bench in a clean one
-        if os.environ.get(_REEXEC_SENTINEL):
-            raise
-        _reexec_with_devices()
-        return
+    from repro.launch.hostenv import ensure_host_devices
+    ensure_host_devices(NEED_DEVICES)   # raises with the recipe if too late
 
     from repro.configs import GH200, get_config
     from repro.core.duplexkv import block_bytes_of

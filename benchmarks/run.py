@@ -57,6 +57,7 @@ def main() -> None:
             only = set(a.split("=", 1)[1].split(",")) if "=" in a else None
     import importlib
     t_all = time.time()
+    failed = []
     for tag, modname in MODULES:
         if only and tag not in only:
             continue
@@ -80,14 +81,19 @@ def main() -> None:
                 TypeError, AssertionError) as e:
             # environment/config failures (missing optional dep, bad grid
             # point, jax backend quirk) and failed headline assertions: log
-            # with full context and move on; anything else propagates
+            # with full context, run the other modules, fail the run at the
+            # end; anything else propagates
             print(f"# {tag} FAILED ({type(e).__name__}):\n"
                   f"{traceback.format_exc()}", flush=True)
             record.update(status="failed",
                           error=f"{type(e).__name__}: {e}")
+            failed.append(tag)
         record["seconds"] = round(time.time() - t0, 1)
         _write_result(tag, record)
     print(f"# total {time.time()-t_all:.0f}s")
+    if failed:
+        print(f"# FAILED modules: {','.join(failed)}", flush=True)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

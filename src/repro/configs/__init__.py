@@ -4,7 +4,8 @@ from repro.configs.base import (ARCH_IDS, GH200, H200_PCIE, HW_PROFILES,
                                 HardwareProfile, LinkProfile, ModelConfig,
                                 MoEConfig, RotaSchedConfig, ServingConfig,
                                 ShapeConfig, SLOConfig, SSMConfig,
-                                all_arch_ids, get_config, shape_applicable)
+                                all_arch_ids, get_config, runner_config,
+                                shape_applicable)
 
 __all__ = [
     "ARCH_IDS", "PAPER_MODEL_IDS", "SHAPES", "LONG_CONTEXT_ARCHS",
@@ -12,5 +13,5 @@ __all__ = [
     "ModelConfig", "MoEConfig", "SSMConfig", "AttentionPattern",
     "FrontendConfig", "HardwareProfile", "LinkProfile", "ShapeConfig",
     "ServingConfig", "SLOConfig", "RotaSchedConfig",
-    "get_config", "all_arch_ids", "shape_applicable",
+    "get_config", "all_arch_ids", "runner_config", "shape_applicable",
 ]

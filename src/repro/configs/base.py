@@ -239,6 +239,31 @@ class ModelConfig:
                               for k, v in kw.items()})
 
 
+def runner_config(cfg: ModelConfig, runner_layers: int = 0) -> ModelConfig:
+    """The model the paged runner executes for ``cfg``.
+
+    ``runner_layers == 0``: ``cfg.reduced()`` in float32, the tiny model
+    the CPU tests run while timing stays calibrated to ``cfg``.
+
+    ``runner_layers == N > 0``: ``cfg`` at its published widths and in its
+    own dtype, cut to its first N layers. This is a depth cut only (the
+    model-configs guide, section 4): d_model, query/kv heads, head_dim,
+    d_ff and the whole vocabulary stay as published, and the left-out
+    layers stand for the further chips a pipelined deployment would put
+    them on. qwen2.5-32b at N=6 is 8.97 GB of bf16 weights (embedding and
+    LM head 3.11 GB, 0.98 GB a layer), which leaves a 16 GB TPU v5e room
+    for a KV pool of a few GB.
+    """
+    if runner_layers < 0:
+        raise ValueError(f"runner_layers must be >= 0, got {runner_layers}")
+    if runner_layers == 0:
+        return dataclasses.replace(cfg.reduced(), dtype="float32")
+    if runner_layers > cfg.num_layers:
+        raise ValueError(f"runner_layers={runner_layers} exceeds "
+                         f"{cfg.name}'s {cfg.num_layers} layers")
+    return dataclasses.replace(cfg, num_layers=runner_layers)
+
+
 # ---------------------------------------------------------------------------
 # Input shapes (the assigned 4-shape set)
 # ---------------------------------------------------------------------------

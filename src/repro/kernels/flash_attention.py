@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -72,7 +74,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Sq, H, D); k/v: (B, Skv, H, D) (pre-repeated GQA heads).
 
     Layout: internally (B*H, S, D). Returns (B, Sq, H, D).
@@ -114,7 +116,7 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),     # running max
             pltpu.VMEM((bq,), jnp.float32),     # running sum
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
 
     out = out[:, :Sq]

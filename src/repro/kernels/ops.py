@@ -1,7 +1,8 @@
 """jit'd public ops: dispatch Pallas TPU kernels on TPU, oracles elsewhere.
 
-``force`` overrides: "pallas" (interpret on CPU — used by tests),
-"ref" (pure-jnp oracle), None (auto: pallas on TPU, ref otherwise).
+``force`` overrides: "pallas" (interpret mode off the TPU, as
+``repro.kernels.resolve_interpret`` decides — used by tests), "ref"
+(pure-jnp oracle), None (auto: pallas on TPU, ref otherwise).
 """
 from __future__ import annotations
 
@@ -25,8 +26,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     force: Optional[str] = None):
     use_pallas = force == "pallas" or (force is None and _on_tpu())
     if use_pallas:
-        return flash_attention_tpu(q, k, v, causal=causal, window=window,
-                                   interpret=not _on_tpu())
+        return flash_attention_tpu(q, k, v, causal=causal, window=window)
     return ref_ops.flash_attention_ref(q, k, v, causal=causal, window=window)
 
 
@@ -35,8 +35,7 @@ def paged_attention(q, kv_pool, block_tables, context_lens, *,
                     force: Optional[str] = None):
     use_pallas = force == "pallas" or (force is None and _on_tpu())
     if use_pallas:
-        return paged_attention_tpu(q, kv_pool, block_tables, context_lens,
-                                   interpret=not _on_tpu())
+        return paged_attention_tpu(q, kv_pool, block_tables, context_lens)
     return ref_ops.paged_attention_ref(q, kv_pool, block_tables, context_lens)
 
 
@@ -44,5 +43,5 @@ def paged_attention(q, kv_pool, block_tables, context_lens, *,
 def kv_copy(pool, src, dst, *, force: Optional[str] = None):
     use_pallas = force == "pallas" or (force is None and _on_tpu())
     if use_pallas:
-        return kv_copy_tpu(pool, src, dst, interpret=not _on_tpu())
+        return kv_copy_tpu(pool, src, dst)
     return ref_ops.kv_copy_ref(pool, src, dst)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -85,7 +87,7 @@ def paged_attention_tpu(q: jax.Array, kv_pool: jax.Array,
                         block_tables: jax.Array, context_lens: jax.Array,
                         *, layer: int = -1,
                         kv_scales: Optional[jax.Array] = None,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, H, D); kv_pool: (NB, 2, P, Hkv, D) block-first;
     block_tables: (B, MB) int32; context_lens: (B,) int32 -> (B, H, D).
 
@@ -149,6 +151,6 @@ def paged_attention_tpu(q: jax.Array, kv_pool: jax.Array,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)
     return out.reshape(B, H, D)

@@ -28,9 +28,10 @@ import dataclasses
 import re
 import sys
 
-_ALIAS_RE = re.compile(
-    r"%arg\d+: tensor<([0-9x]+)x[a-z0-9]+>\s*"
-    r"(\{[^}]*(?:tf\.aliasing_output|jax\.buffer_donor)[^}]*\})?")
+_ARG_RE = re.compile(r"%arg\d+: tensor<([0-9x]+)x[a-z0-9]+>")
+# an argument's attribute dict may nest braces (Shardy's #sdy.sharding), so
+# each argument's text runs to the next "%argN:" rather than the first "}"
+_DONATION_MARKERS = ("tf.aliasing_output", "jax.buffer_donor")
 
 
 def _pool_alias(lowered_text: str, pool_shape) -> tuple:
@@ -39,10 +40,11 @@ def _pool_alias(lowered_text: str, pool_shape) -> tuple:
     found = aliased = 0
     main = lowered_text.split("func.func public @main", 1)[-1]
     sig = main.split("->", 1)[0]
-    for dims, alias in _ALIAS_RE.findall(sig):
-        if dims == want:
+    for arg in re.split(r"(?=%arg\d+:)", sig):
+        m = _ARG_RE.match(arg)
+        if m and m.group(1) == want:
             found += 1
-            if alias:
+            if any(k in arg for k in _DONATION_MARKERS):
                 aliased += 1
     return found, aliased
 
@@ -151,10 +153,9 @@ def main(argv=None) -> int:
     # the bare kernel jitted WITHOUT donate_argnums: its internal
     # input_output_aliases cannot reach the boundary alone — a regression
     # guard that the audit detects missing donation (negative control)
-    import functools
     from repro.kernels.kv_copy import kv_copy_tpu
     flat = pool.reshape(ps[0], -1)
-    bare = jax.jit(functools.partial(kv_copy_tpu, interpret=True))
+    bare = jax.jit(kv_copy_tpu)
     cases.append(("kv_copy_tpu (no donate — negative control)", bare,
                   (flat, two, two), False, [flat.shape]))
 

@@ -61,8 +61,6 @@ def _compile_once(cfg, shape, mesh, rules, *, microbatches, unroll,
     # bug) must propagate, not be recorded as a soft analysis failure.
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
         rec["cost_analysis"] = {k: float(v) for k, v in cost.items()
                                 if isinstance(v, (int, float))
                                 and k in ("flops", "bytes accessed",

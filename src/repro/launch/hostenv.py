@@ -1,6 +1,8 @@
 """Host-environment tuning knobs shared by serve entry points and CI.
 
-Two concerns, both of which must act BEFORE the first ``jax`` import:
+``enable_compile_cache()`` points JAX's persistent compilation cache at a
+fixed directory. The other two concerns must act BEFORE the first ``jax``
+import:
 
 * ``ensure_host_devices(n)`` — a CPU host exposes one XLA device unless
   ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` is set at import
@@ -17,8 +19,28 @@ from __future__ import annotations
 
 import os
 import sys
+from pathlib import Path
 
 _FLAG = "--xla_force_host_platform_device_count"
+# fixed and checkout-relative: a cache whose directory moves between runs
+# (temporary, pid- or time-based) never hits
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is what JAX already uses and
+    nothing here overrides it. Otherwise the cache goes to ``.jax_cache/``
+    at the checkout root, so a second run of the same entry point in the
+    same checkout skips the compiles of the first. Call after
+    ``ensure_host_devices`` (this imports jax)."""
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return str(CHECKOUT_CACHE_DIR)
 
 
 def ensure_host_devices(n: int) -> None:

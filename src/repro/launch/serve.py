@@ -30,6 +30,13 @@ same budget holds ~2x blocks under int8; ``block_bytes``/``d2h_bytes``/
     PYTHONPATH=src python -m repro.launch.serve --rps 20 --duration 40 \
         --kv-dtype int8 --hbm-budget-gb 60 --paged-runner --json
 
+Real execution at published widths (the TPU path): ``--runner-layers N``
+runs ``--model`` in its own dtype cut to its first N layers, and the pool,
+block bytes and timing then all follow that executed model:
+
+    PYTHONPATH=src python -m repro.launch.serve --paged-runner \
+        --runner-layers 6 --hbm-budget-gb 4 --hw tpu-v5e --rps 1 --json
+
 Disaggregated prefill/decode serving with cross-replica KV migration over
 the DRAM tier (``migrations``/``migration_*`` counters land in the output;
 best exercised under a bursty trace):
@@ -54,9 +61,10 @@ def main(argv=None):
     pre = argparse.ArgumentParser(add_help=False)
     pre.add_argument("--tp", type=int, default=1)
     pre_args, _ = pre.parse_known_args(argv)
+    from repro.launch.hostenv import enable_compile_cache, ensure_host_devices
     if pre_args.tp > 1:
-        from repro.launch.hostenv import ensure_host_devices
         ensure_host_devices(pre_args.tp)
+    enable_compile_cache()
 
     from repro.serving.router import ROUTER_POLICIES
 
@@ -123,13 +131,20 @@ def main(argv=None):
     ap.add_argument("--prefix-count", type=int, default=8,
                     help="number of distinct shared prefixes")
     ap.add_argument("--paged-runner", action="store_true",
-                    help="execute tokens for REAL on a reduced model over "
-                         "the pooled block-first KV cache (batched Pallas "
-                         "paged-attention decode; rotation physically moves "
-                         "pool rows). Timing stays calibrated to --model. "
-                         "The trace is clamped to smoke scale (short "
-                         "prompts/outputs, reduced vocab) so interpret-mode "
-                         "kernels stay fast on CPU.")
+                    help="execute tokens for REAL over the pooled "
+                         "block-first KV cache (batched Pallas paged-"
+                         "attention decode; rotation physically moves pool "
+                         "rows). Without --runner-layers the executed model "
+                         "is a reduced float32 one, timing stays calibrated "
+                         "to --model, and the trace is clamped to smoke "
+                         "scale (short prompts/outputs, reduced vocab) so "
+                         "interpret-mode kernels stay fast on CPU.")
+    ap.add_argument("--runner-layers", type=int, default=0, metavar="N",
+                    help="with --paged-runner: execute --model at its "
+                         "published widths and dtype, cut to its first N "
+                         "layers (no trace clamps); block bytes, "
+                         "--hbm-budget-gb sizing and timing follow that "
+                         "executed model. 0 (default) = reduced model")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor parallelism: shard the paged runner's KV "
                          "pool, Pallas kernels, and weights over a "
@@ -138,9 +153,9 @@ def main(argv=None):
                          "act before the first jax import); tp=1 (default) "
                          "is the bit-identical single-chip path")
     ap.add_argument("--paged-max-prompt", type=int, default=40,
-                    help="prompt-length clamp under --paged-runner")
+                    help="prompt-length clamp for the reduced model")
     ap.add_argument("--paged-max-output", type=int, default=8,
-                    help="output-length clamp under --paged-runner")
+                    help="output-length clamp for the reduced model")
     ap.add_argument("--kv-dtype", choices=["bf16", "int8"], default="bf16",
                     help="KV cache storage dtype. int8 selects the blockwise"
                          "-quantized tier: the paged pool stores int8 rows + "
@@ -184,8 +199,11 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
+    if args.runner_layers and not args.paged_runner:
+        ap.error("--runner-layers needs --paged-runner")
 
-    from repro.configs import HW_PROFILES, RotaSchedConfig, ServingConfig, get_config
+    from repro.configs import (HW_PROFILES, RotaSchedConfig, ServingConfig,
+                               get_config, runner_config)
     from repro.serving.disagg import DisaggCluster
     from repro.serving.engine import ServingEngine
     from repro.serving.router import Router
@@ -194,6 +212,11 @@ def main(argv=None):
                                         generate_shared_prefix_requests)
 
     cfg = get_config(args.model)
+    runner_cfg = None
+    if args.paged_runner:
+        runner_cfg = runner_config(cfg, args.runner_layers)
+        if args.runner_layers:
+            cfg = runner_cfg        # one model: executed, sized and timed
     rot = RotaSchedConfig(alpha=args.alpha, beta_b=args.beta_b,
                           beta_f=args.beta_f,
                           b_xfer=args.b_xfer if args.b_xfer else 2400)
@@ -237,21 +260,20 @@ def main(argv=None):
                                  seed=args.seed, arrival=args.arrival,
                                  arrival_kw=arrival_kw)
 
-    runner_cfg = None
     if args.paged_runner:
         import dataclasses as _dc
         import numpy as _np
-        # real execution on CPU: a reduced fp32 model; clamp the trace to
-        # smoke scale and remap token ids into the reduced vocab (prompts
-        # without ids get deterministic synthetic ones)
-        runner_cfg = _dc.replace(cfg.reduced(), dtype="float32")
+        # real execution: remap token ids into the executed vocab (prompts
+        # without ids get deterministic synthetic ones); the reduced model
+        # also clamps the trace to smoke scale for the CPU interpreter
         rng = _np.random.default_rng([args.seed, 0xBA9ED])
         for r in reqs:
-            r.prompt_len = min(r.prompt_len, args.paged_max_prompt)
-            r.output_len = min(r.output_len, args.paged_max_output)
-            if r.sampling is not None:
-                r.sampling = _dc.replace(
-                    r.sampling, max_tokens=r.output_len)
+            if not args.runner_layers:
+                r.prompt_len = min(r.prompt_len, args.paged_max_prompt)
+                r.output_len = min(r.output_len, args.paged_max_output)
+                if r.sampling is not None:
+                    r.sampling = _dc.replace(
+                        r.sampling, max_tokens=r.output_len)
             if r.prompt_ids is None:
                 r.prompt_ids = [int(x) for x in rng.integers(
                     1, runner_cfg.vocab_size, r.prompt_len)]

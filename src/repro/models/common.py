@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -24,7 +25,10 @@ def is_param_def(x) -> bool:
     return isinstance(x, ParamDef)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def init_param(rng: jax.Array, d: ParamDef, dtype) -> jax.Array:
+    # jitted so the float32 draw fuses into the cast: eagerly, a stacked
+    # (layers, d_model, d_ff) leaf would hold two float32 copies at once
     if d.init == "zeros":
         return jnp.zeros(d.shape, dtype)
     if d.init == "ones":

@@ -31,9 +31,9 @@ same integers the scheduler budgets with. Consequences:
   after the wo and w_down contractions. ``tp == 1`` takes none of these
   branches and stays bit-identical to the single-chip runner.
 
-Pallas kernels run in interpret mode under ``jax.jit`` on CPU (tier-1 CI);
-on a real TPU the same calls lower to Mosaic. See DESIGN.md §Execution
-layer for the faithfulness discussion.
+Pallas kernels compile to Mosaic on a TPU backend and run in the Pallas
+interpreter elsewhere (the CPU tests); ``repro.kernels.resolve_interpret``
+makes that choice for every launch. See DESIGN.md §Execution layer.
 """
 from __future__ import annotations
 
@@ -58,6 +58,29 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def _unstack_layers(program, segments: list) -> List[dict]:
+    """Per-layer param dicts in execution order (segment -> repeat ->
+    pattern position), unstacking the scan-over-layers stacks of
+    ``LM.init``'s ``segments`` (consumed). Each stacked leaf is dropped as
+    soon as it is sliced, so peak device memory is the weights plus one
+    leaf's stack rather than two copies of every layer."""
+    import jax
+    out: List[dict] = []
+    for si, seg in enumerate(program):
+        leaves, treedef = jax.tree.flatten(segments[si])
+        segments[si] = None
+        if seg.repeat == 1:
+            out.extend(jax.tree.unflatten(treedef, leaves))
+            continue
+        cols = []
+        for j in range(len(leaves)):
+            cols.append([leaves[j][r] for r in range(seg.repeat)])
+            leaves[j] = None
+        for r in range(seg.repeat):
+            out.extend(jax.tree.unflatten(treedef, [c[r] for c in cols]))
+    return out
+
+
 class PagedKVStore:
     """Physical two-tier KV storage behind the block table's slot numbers.
 
@@ -70,7 +93,7 @@ class PagedKVStore:
     """
 
     def __init__(self, cfg: ModelConfig, serving: ServingConfig, dtype,
-                 *, staging: int = 64, interpret: bool = True,
+                 *, staging: int = 64,
                  double_buffer: bool = False, tp_plan=None, mesh=None,
                  kv_dtype: str = "bf16"):
         import jax
@@ -151,7 +174,6 @@ class PagedKVStore:
                 self.scales = jnp.zeros(scale_shape, jnp.float32)
         # dram_slot -> row array (bf16) | (int8 row, fp32 scale row) tuple
         self.host: Dict[int, np.ndarray] = {}
-        self.interpret = interpret
         # counters (benchmarks / tests)
         self.copy_launches = 0
         self.d2d_rows = 0
@@ -166,12 +188,9 @@ class PagedKVStore:
         from repro.kernels.kv_copy import kv_copy_tpu
 
         def _copy(pool, src, dst):
-            # reshape happens INSIDE shard_map (on the local block) in tp
-            # mode — flattening the sharded array outside would force an
-            # all-gather and destroy the sharding
-            flat = pool.reshape(pool.shape[0], -1)
-            out = kv_copy_tpu(flat, src, dst, interpret=interpret)
-            return out.reshape(pool.shape)
+            # the pool keeps its (rows, L, 2, P, Hkv, D) layout: flattening
+            # rows would relayout (copy) the whole pool on a TPU
+            return kv_copy_tpu(pool, src, dst)
 
         def _upload(pool, rows, base):   # contiguous write into staging
             idx = (base,) + (0,) * (pool.ndim - 1)
@@ -183,11 +202,7 @@ class PagedKVStore:
         # the block's payload, so every direction (D2D fork, D2H gather,
         # H2D scatter) carries both or the dequant would read stale scales.
         def _copy_q(pool, scales, src, dst):
-            flat = pool.reshape(pool.shape[0], -1)
-            out = kv_copy_tpu(flat, src, dst, interpret=interpret)
-            sflat = scales.reshape(scales.shape[0], -1)
-            sout = kv_copy_tpu(sflat, src, dst, interpret=interpret)
-            return out.reshape(pool.shape), sout.reshape(scales.shape)
+            return kv_copy_tpu(pool, src, dst), kv_copy_tpu(scales, src, dst)
 
         def _upload_q(pool, scales, rows, srows, base):
             idx = (base,) + (0,) * (pool.ndim - 1)
@@ -199,24 +214,21 @@ class PagedKVStore:
             return pool, scales
 
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as Pspec
             ps = self._pool_spec
             ss = self._scale_spec
-            # check_rep=False: pallas calls inside shard_map can't prove
+            # check_vma=False: pallas calls inside shard_map can't prove
             # replication; correctness is covered by the tp parity tests
-            _copy = shard_map(_copy, mesh=mesh,
-                              in_specs=(ps, Pspec(), Pspec()),
-                              out_specs=ps, check_rep=False)
-            _upload = shard_map(_upload, mesh=mesh,
-                                in_specs=(ps, ps, Pspec()),
-                                out_specs=ps, check_rep=False)
-            _copy_q = shard_map(_copy_q, mesh=mesh,
-                                in_specs=(ps, ss, Pspec(), Pspec()),
-                                out_specs=(ps, ss), check_rep=False)
-            _upload_q = shard_map(_upload_q, mesh=mesh,
-                                  in_specs=(ps, ss, ps, ss, Pspec()),
-                                  out_specs=(ps, ss), check_rep=False)
+            smap = functools.partial(jax.shard_map, mesh=mesh,
+                                     check_vma=False)
+            _copy = smap(_copy, in_specs=(ps, Pspec(), Pspec()),
+                         out_specs=ps)
+            _upload = smap(_upload, in_specs=(ps, ps, Pspec()),
+                           out_specs=ps)
+            _copy_q = smap(_copy_q, in_specs=(ps, ss, Pspec(), Pspec()),
+                           out_specs=(ps, ss))
+            _upload_q = smap(_upload_q, in_specs=(ps, ss, ps, ss, Pspec()),
+                             out_specs=(ps, ss))
 
         # donate the pool: the caller always rebinds to the returned array,
         # and without donation every launch would deep-copy the whole pool,
@@ -250,12 +262,14 @@ class PagedKVStore:
 
     def _copy_rows(self, src: Sequence[int], dst: Sequence[int]) -> None:
         """One batched row-copy launch: pool[dst[i]] = pool[src[i]].
-        Padded to a power of two with no-op descriptors (src < 0)."""
+        Padded to a power of two with no-op descriptors (src < 0) aimed at
+        the trash row: the chip writes back every visited output block, so
+        a padded lane must never name a live row."""
         import jax.numpy as jnp
         n = len(src)
         np2 = _pow2(n)
         s = np.full(np2, -1, np.int32)
-        d = np.zeros(np2, np.int32)
+        d = np.full(np2, self.trash_row, np.int32)
         s[:n], d[:n] = src, dst
         import jax
         t0 = time.perf_counter()
@@ -364,8 +378,9 @@ class PagedKVStore:
 class PagedModelRunner(Executor):
     """Batched real execution against the pooled block-first KV cache.
 
-    ``model_cfg`` is the config actually executed (a ``reduced()`` tiny LM
-    on CPU); iteration wall-time still comes from a ``SimExecutor`` — pass
+    ``model_cfg`` is the config actually executed (``runner_config``: a
+    ``reduced()`` tiny LM for the CPU tests, or the published widths cut in
+    depth); iteration wall-time still comes from a ``SimExecutor`` — pass
     ``timing_cfg`` to keep timing calibrated to the full-size model while
     executing the reduced one. The runner binds to the engine's DuplexKV
     (``bind``), sizing the device pool to the block table and attaching its
@@ -377,9 +392,9 @@ class PagedModelRunner(Executor):
     def __init__(self, model_cfg: ModelConfig, serving: ServingConfig,
                  hw: HardwareProfile = GH200, *, seed: int = 0,
                  sim: Optional[SimExecutor] = None,
-                 timing_cfg: Optional[ModelConfig] = None,
-                 interpret: bool = True):
+                 timing_cfg: Optional[ModelConfig] = None):
         import jax
+        from repro.kernels import resolve_interpret
         from repro.models.blocks import make_layer_spec
         from repro.models.common import dtype_of
         from repro.models.lm import LM
@@ -411,15 +426,13 @@ class PagedModelRunner(Executor):
         self.tp_plan = plan_tp_sharding(model_cfg, self.tp)
         self.sim = sim or SimExecutor(timing_cfg or model_cfg, hw,
                                       tp=self.tp, kv_dtype=self.kv_dtype)
-        self.interpret = interpret
+        self.interpret = resolve_interpret()
         self.dtype = dtype_of(model_cfg.dtype)
-        self.lm = LM(model_cfg)
-        self.params = self.lm.init(jax.random.PRNGKey(seed))
-        self._layers = self._flatten_layers()
-        self._head = {k: self.params[k] for k in
-                      ("embed", "final_norm") if k in self.params}
-        if "lm_head" in self.params:
-            self._head["lm_head"] = self.params["lm_head"]
+        lm = LM(model_cfg)
+        params = lm.init(jax.random.PRNGKey(seed))
+        self._head = {k: params.pop(k) for k in
+                      ("embed", "final_norm", "lm_head") if k in params}
+        self._layers = _unstack_layers(lm.program, params.pop("segments"))
         self.store: Optional[PagedKVStore] = None
         self.kv = None
         # psum flags are trace-time constants: at tp == 1 neither branch is
@@ -442,7 +455,6 @@ class PagedModelRunner(Executor):
                 self._jit_prefill = jax.jit(self._prefill_impl,
                                             donate_argnums=(2,))
         else:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as Pspec
             from repro.distributed.tp import (head_pspecs, layer_pspecs,
@@ -454,39 +466,46 @@ class PagedModelRunner(Executor):
             layer_specs = [{k: lp[k] for k in layer} for layer in self._layers]
             head_specs = head_pspecs(self._head)
             # shard the weights once, up front (device_put per spec); jit
-            # then consumes them already laid out — no per-step resharding
-            self._layers = [
-                {k: jax.device_put(v, NamedSharding(self.mesh, lp[k]))
-                 for k, v in layer.items()} for layer in self._layers]
-            self._head = {
-                k: jax.device_put(v, NamedSharding(self.mesh, head_specs[k]))
-                for k, v in self._head.items()}
+            # then consumes them already laid out — no per-step resharding.
+            # Leaves are replaced in place so each unsharded leaf is freed
+            # as soon as it is placed: init put every weight on device 0
+            for layer in self._layers:
+                for k in layer:
+                    layer[k] = jax.device_put(
+                        layer[k], NamedSharding(self.mesh, lp[k]))
+            for k in self._head:
+                self._head[k] = jax.device_put(
+                    self._head[k], NamedSharding(self.mesh, head_specs[k]))
             ps = pool_pspec(self.tp_plan)
+            # check_vma=False: pallas calls inside shard_map can't prove
+            # replication; correctness is covered by the tp parity tests
+            shard_map = functools.partial(jax.shard_map, mesh=self.mesh,
+                                          check_vma=False)
             if self.quantized:
                 ss = scale_pspec(self.tp_plan)
                 dec = shard_map(
-                    self._decode_impl_q, mesh=self.mesh,
+                    self._decode_impl_q,
                     in_specs=(layer_specs, head_specs, ps, ss,
                               Pspec(), Pspec(), Pspec()),
-                    out_specs=(ps, ss, Pspec()), check_rep=False)
+                    out_specs=(ps, ss, Pspec()))
                 pre = shard_map(
-                    self._prefill_impl_q, mesh=self.mesh,
+                    self._prefill_impl_q,
                     in_specs=(layer_specs, head_specs, ps, ss,
                               Pspec(), Pspec(), Pspec(), Pspec()),
-                    out_specs=(ps, ss, Pspec()), check_rep=False)
+                    out_specs=(ps, ss, Pspec()))
                 self._jit_decode = jax.jit(dec, donate_argnums=(2, 3))
                 self._jit_prefill = jax.jit(pre, donate_argnums=(2, 3))
             else:
                 dec = shard_map(
-                    self._decode_impl, mesh=self.mesh,
+                    self._decode_impl,
                     in_specs=(layer_specs, head_specs, ps,
                               Pspec(), Pspec(), Pspec()),
-                    out_specs=(ps, Pspec()), check_rep=False)
+                    out_specs=(ps, Pspec()))
                 pre = shard_map(
-                    self._prefill_impl, mesh=self.mesh,
+                    self._prefill_impl,
                     in_specs=(layer_specs, head_specs, ps,
                               Pspec(), Pspec(), Pspec(), Pspec()),
-                    out_specs=(ps, Pspec()), check_rep=False)
+                    out_specs=(ps, Pspec()))
                 self._jit_decode = jax.jit(dec, donate_argnums=(2,))
                 self._jit_prefill = jax.jit(pre, donate_argnums=(2,))
         # counters (benchmarks / tests): decode launch count is per-layer,
@@ -506,26 +525,11 @@ class PagedModelRunner(Executor):
         to its block table and register as the physical data backend."""
         self.kv = kv
         self.store = PagedKVStore(
-            self.cfg, self.serving, self.dtype, interpret=self.interpret,
+            self.cfg, self.serving, self.dtype,
             double_buffer=bool(getattr(self.serving, "pipeline", False)),
             tp_plan=None if self.tp_plan.trivial else self.tp_plan,
             mesh=self.mesh, kv_dtype=self.kv_dtype)
         kv.attach_data_backend(self.store)
-
-    def _flatten_layers(self) -> List[dict]:
-        """Per-layer param dicts in execution order (segment -> repeat ->
-        pattern position), unstacking scan-over-layers stacks."""
-        import jax
-        out = []
-        for si, seg in enumerate(self.lm.program):
-            p_seg = self.params["segments"][si]
-            for rep in range(seg.repeat):
-                for pi in range(len(seg.pattern)):
-                    p = p_seg[pi]
-                    if seg.repeat > 1:
-                        p = jax.tree.map(lambda a, r=rep: a[r], p)
-                    out.append(p)
-        return out
 
     # ------------------------------------------------------ executor protocol
     def step_time(self, plan) -> float:

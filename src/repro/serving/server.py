@@ -83,7 +83,10 @@ class ServerConfig:
     decode_replicas: int = 1
     pipeline: bool = False
     prefix_cache: bool = False
-    paged_runner: bool = False        # real reduced-model execution
+    paged_runner: bool = False        # real execution (paged runner)
+    # paged runner at --model's published widths cut to this many layers
+    # (configs.runner_config); 0 = the reduced float32 model
+    runner_layers: int = 0
     tp: int = 1                       # tensor parallelism (devices/replica)
     kv_dtype: str = "bf16"            # "int8" = quantized KV tier
     hbm_blocks: int = 4000
@@ -124,6 +127,10 @@ class ServerConfig:
             problems.append("replicas must be >= 1")
         if self.tp < 1:
             problems.append("tp must be >= 1")
+        if self.runner_layers < 0:
+            problems.append("runner_layers must be >= 0")
+        elif self.runner_layers and not self.paged_runner:
+            problems.append("runner_layers needs paged_runner")
         if self.kv_dtype not in ("bf16", "int8"):
             problems.append(f"kv_dtype must be 'bf16' or 'int8', "
                             f"got {self.kv_dtype!r}")
@@ -162,11 +169,17 @@ class ServerConfig:
             # XLA device unless the flag is set at import time)
             from repro.launch.hostenv import ensure_host_devices
             ensure_host_devices(self.tp)
-        from repro.configs import HW_PROFILES, ServingConfig, get_config
+        from repro.configs import (HW_PROFILES, ServingConfig, get_config,
+                                   runner_config)
         from repro.serving.core import EngineCore
         from repro.serving.disagg import DisaggCluster
         from repro.serving.router import Router
         cfg = get_config(self.model)
+        runner_cfg = None
+        if self.paged_runner:
+            runner_cfg = runner_config(cfg, self.runner_layers)
+            if self.runner_layers:
+                cfg = runner_cfg    # one model: executed, sized and timed
         sv = ServingConfig(num_hbm_blocks=self.hbm_blocks,
                            num_dram_blocks=self.dram_blocks,
                            scheduler=self.scheduler,
@@ -177,9 +190,6 @@ class ServerConfig:
                            kv_dtype=self.kv_dtype,
                            telemetry=self.telemetry)
         hw = HW_PROFILES[self.hw]
-        runner_cfg = None
-        if self.paged_runner:   # real execution: reduced fp32 model on CPU
-            runner_cfg = dataclasses.replace(cfg.reduced(), dtype="float32")
         if self.disagg:
             return DisaggCluster(cfg, sv, hw,
                                  prefill_replicas=self.prefill_replicas,
@@ -715,6 +725,9 @@ def main(argv=None) -> int:
     else:
         raw = json.loads(args.config_json)
     cfg = ServerConfig.from_dict(raw).validate()
+    from repro.launch.hostenv import enable_compile_cache, ensure_host_devices
+    ensure_host_devices(cfg.tp)        # before the cache helper imports jax
+    enable_compile_cache()
     return asyncio.run(serve_main(cfg))
 
 

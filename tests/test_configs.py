@@ -67,3 +67,39 @@ def test_gemma3_local_global():
     cfg = get_config("gemma3-1b")
     globals_ = [i for i in range(cfg.num_layers) if cfg.layer_is_global(i)]
     assert globals_ == [5, 11, 17, 23]
+
+
+@pytest.mark.parametrize("arch,layers", [("qwen2.5-32b", 6), ("yi-34b", 1)])
+def test_runner_config_cuts_depth_only(arch, layers):
+    """runner_layers keeps every published width and the model's own dtype,
+    cuts depth, and the pool's rows (hence --hbm-budget-gb sizing) are the
+    executed model's rows, not the full-depth timing model's."""
+    import dataclasses
+    import jax.numpy as jnp
+    from repro.configs import ServingConfig, runner_config
+    from repro.core.duplexkv import block_bytes_of, hbm_block_capacity
+    from repro.serving.paged_runner import PagedKVStore
+    full = get_config(arch)
+    cut = runner_config(full, layers)
+    assert cut.num_layers == layers and cut.dtype == full.dtype == "bfloat16"
+    assert dataclasses.replace(cut, num_layers=full.num_layers) == full
+    bb, _ = block_bytes_of(cut, 16)
+    assert bb == 16 * 2 * cut.num_kv_heads * cut.head_dim * 2 * layers
+    assert bb * full.num_layers == block_bytes_of(full, 16)[0] * layers
+    assert hbm_block_capacity(cut, 16, 4 << 30) == (4 << 30) // bb
+    store = PagedKVStore(cut, ServingConfig(num_hbm_blocks=2), jnp.bfloat16,
+                         staging=1)
+    row = store.pool[0]
+    assert row.nbytes == bb and row.shape == (layers, 2, 16, cut.num_kv_heads,
+                                              cut.head_dim)
+
+
+def test_runner_config_default_is_reduced_float32():
+    from repro.configs import runner_config
+    cfg = get_config("qwen2.5-32b")
+    red = runner_config(cfg)
+    assert red.dtype == "float32" and red.d_model == cfg.reduced().d_model
+    with pytest.raises(ValueError):
+        runner_config(cfg, -1)
+    with pytest.raises(ValueError):
+        runner_config(cfg, cfg.num_layers + 1)

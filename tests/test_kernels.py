@@ -110,9 +110,51 @@ def test_kv_copy_sweep(NB, F, N, dtype):
     # mark one descriptor invalid
     if N > 1:
         src = src.at[0].set(-1)
-    out = kv_copy_tpu(pool, src, dst, tile_bytes=64)
+    out = kv_copy_tpu(pool, src, dst)
     want = ref.kv_copy_ref(pool, src, dst)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+@pytest.mark.parametrize("row_shape,dtype", [
+    ((3, 2, 4, 2, 8), jnp.bfloat16),   # (L, 2, P, Hkv, D) pool row
+    ((3, 2, 4, 2, 8), jnp.int8),       # int8 pool row
+    ((3, 2, 2), jnp.float32),          # (L, 2, Hkv) int8-tier scale row
+])
+def test_kv_copy_pool_rows_match_ref(row_shape, dtype):
+    """Multi-dim rows (the pool's own layout, copied slab by slab along
+    dim 1) agree with the oracle, padded lanes included."""
+    NB = 12
+    if dtype == jnp.int8:
+        pool = jnp.asarray(RNG.integers(-100, 100, (NB,) + row_shape), dtype)
+    else:
+        pool = jnp.asarray(RNG.standard_normal((NB,) + row_shape), dtype)
+    src = jnp.asarray([4, 7, 1, -1], jnp.int32)
+    dst = jnp.asarray([9, 2, 5, 11], jnp.int32)
+    out = kv_copy_tpu(pool, src, dst)
+    want = ref.kv_copy_ref(pool, src, dst)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def test_kv_copy_padded_batch_leaves_other_rows_bit_identical():
+    """The store pads a batch to a power of two with (src=-1, dst=trash)
+    lanes: only the real destinations change — row 0 and every other row,
+    the trash row included, keep their exact bits."""
+    from repro.configs import ServingConfig, get_config
+    from repro.serving.paged_runner import PagedKVStore
+    cfg = get_config("qwen2.5-32b").reduced()
+    sv = ServingConfig(num_hbm_blocks=10, block_size=4)
+    store = PagedKVStore(cfg, sv, jnp.bfloat16, staging=4)
+    before = np.asarray(RNG.standard_normal(store.pool.shape), np.float32)
+    store.pool = jnp.asarray(before, jnp.bfloat16)
+    before = np.asarray(store.pool)
+    src, dst = [3, 8, 5], [6, 1, 9]                  # 3 lanes -> padded to 4
+    store._copy_rows(src, dst)
+    after = np.asarray(store.pool)
+    for s_, d_ in zip(src, dst):
+        np.testing.assert_array_equal(after[d_], before[s_])
+    untouched = [r for r in range(after.shape[0]) if r not in dst]
+    assert 0 in untouched and store.trash_row in untouched
+    np.testing.assert_array_equal(after[untouched], before[untouched])
 
 
 def test_ops_dispatch_cpu_uses_ref():

@@ -75,8 +75,6 @@ def test_extrapolation_identity_small():
         cfg = dataclasses.replace(base, num_layers=L)
         b = make_step_bundle(cfg, shape, unroll=True)
         c = jax.jit(b.fn).lower(*b.args_structs).compile().cost_analysis()
-        if isinstance(c, list):   # older jax: one dict per device
-            c = c[0]
         return float(c["flops"])
 
     f1, f2, f3 = flops_at(1), flops_at(2), flops_at(3)

@@ -149,6 +149,43 @@ def test_config_validation():
         assert frag in msg
     cfg = ServerConfig.from_dict({"port": 0, "replicas": 2})
     assert cfg.validate() is cfg
+    with pytest.raises(ValueError, match="runner_layers needs paged_runner"):
+        ServerConfig(runner_layers=6).validate()
+    assert ServerConfig(runner_layers=6, paged_runner=True).validate()
+
+
+def test_launcher_import_loads_no_jax():
+    """The supervisor must stay off JAX: a parent that has touched JAX holds
+    the chip, and its server child could then not reach it."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    code = ("import sys, repro.launch.server_main; "
+            "sys.exit(1 if 'jax' in sys.modules else 0)")
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          timeout=120).returncode == 0
+
+
+def test_compile_cache_dir_follows_env(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only cache directory;
+    unset, the entry points use the fixed .jax_cache/ of the checkout."""
+    import jax
+    from repro.launch import hostenv
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert hostenv.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = hostenv.enable_compile_cache()
+        assert path == jax.config.jax_compilation_cache_dir
+        assert path.endswith(".jax_cache")
+        assert (hostenv.CHECKOUT_CACHE_DIR.parent / "src").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 # ---------------------------------------------------------------- endpoints
