@@ -142,6 +142,12 @@ class Request:
     pre_token_rotary_s: float = 0.0
     # non-None while the request sits in ROTARY pre-first-token
     _t_rotary_since: Optional[float] = None
+    # -- host-clock stamps (time.perf_counter_ns) for the flight recorder:
+    # receipt by the front door, admission, first token emitted. Never read
+    # by the engine clock, RotaSched or SLOReport.
+    recv_ns: Optional[int] = None
+    admit_ns: Optional[int] = None
+    first_token_ns: Optional[int] = None
 
     @property
     def prefill_done(self) -> bool:

@@ -358,18 +358,9 @@ def main(argv=None):
             attn_launches=sum(e.attn_launches for e in execs),
             kv_copy_launches=sum(e.store.copy_launches for e in execs),
             kv_rows_moved=sum(e.store.d2h_rows + e.store.h2d_rows
-                              + e.store.d2d_rows for e in execs),
-            # host-side dispatch wall time (observability; sim clock is
-            # still the timing authority)
-            prefill_launch_wall_s=round(
-                sum(e.prefill_launch_wall_s for e in execs), 6),
-            decode_launch_wall_s=round(
-                sum(e.decode_launch_wall_s for e in execs), 6),
-            kv_copy_launch_wall_s=round(
-                sum(e.store.copy_launch_wall_s
-                    + e.store.upload_launch_wall_s for e in execs), 6))
+                              + e.store.d2d_rows for e in execs))
     if sv.telemetry:
-        from repro.serving.telemetry import buses_of
+        from repro.serving.telemetry import HS_RUNNER_LAUNCH, buses_of
         from repro.serving.trace_export import write_trace
         buses = buses_of(cores)
         row.update(telemetry=dict(
@@ -377,6 +368,18 @@ def main(argv=None):
             spans_dropped=sum(b.spans_dropped for b in buses),
             events=sum(b.events_recorded for b in buses),
             events_dropped=sum(b.events_dropped for b in buses)))
+        if args.paged_runner:
+            # host-clock seconds in the runner's launches and the KV
+            # store's transfers, summed over replicas (host spans)
+            host_s: dict = {}
+            for b in buses:
+                for name, c in b.host_counters()["spans"].items():
+                    if (name == HS_RUNNER_LAUNCH
+                            or name.startswith("superinfer.kvstore.")):
+                        host_s[name] = host_s.get(name, 0.0) \
+                            + c["total_ns"] * 1e-9
+            row["telemetry"]["host_span_s"] = {
+                k: round(v, 6) for k, v in sorted(host_s.items())}
         if args.trace_out:
             write_trace(args.trace_out, cores)
             row.update(trace_out=args.trace_out)

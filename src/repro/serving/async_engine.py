@@ -36,9 +36,10 @@ that thread to an asyncio event loop (see DESIGN.md §Service layer):
   ``Condition.wait()``; submissions/aborts/shutdown notify it.
 * **Shutdown** — ``shutdown(drain_timeout_s)`` stops admission
   (``ServiceDraining`` on new submits), drains bounded by *wall* seconds
-  (``drain_wallclock``, satellite of this PR), aborts whatever remains so
-  every open stream terminates (``finish_reason == "aborted"`` and blocks
-  are freed), and returns the unfinished ids (non-empty => dirty drain).
+  (``drain_wallclock``; paced like serving when ``pace`` is on), aborts
+  whatever remains so every open stream terminates (``finish_reason ==
+  "aborted"`` and blocks are freed), and returns the unfinished ids
+  (non-empty => dirty drain).
 
 Works over any engine-like object: ``EngineCore`` / ``ServingEngine`` (its
 core is unwrapped), ``Router``, ``DisaggCluster``.
@@ -55,6 +56,8 @@ from typing import Any, AsyncIterator, Callable, Dict, List, Optional, Tuple
 
 from repro.core.types import RequestOutput, SamplingParams
 from repro.serving.outputs import RequestHandle
+from repro.serving.telemetry import (HS_DRIVER_CONTROL, HS_DRIVER_DELIVER,
+                                     HS_DRIVER_WAIT, host_span)
 
 DRIVER_NAME = "AsyncServingEngine"
 
@@ -156,6 +159,10 @@ class AsyncServingEngine:
                                 f"DisaggCluster")
         self.pace = pace
         self.name = name
+        # the flight recorder the front door's and the driver's host spans
+        # go to: the first replica's bus (None when telemetry is off)
+        cores = getattr(self.engine, "replicas", None) or [self.engine]
+        self.telemetry = getattr(cores[0], "telemetry", None)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
         self._cv = threading.Condition()
@@ -232,12 +239,18 @@ class AsyncServingEngine:
                      sampling_params: Optional[SamplingParams] = None,
                      slo_class: str = "standard",
                      slo=None,
-                     arrival_time: Optional[float] = None
+                     arrival_time: Optional[float] = None,
+                     recv_ns: Optional[int] = None
                      ) -> AsyncRequestHandle:
         """Submit a request; resolves once the driver thread registered it.
         ``arrival_time`` defaults to "now" on the wall-anchored engine clock
-        (explicit values are the replay/testing path, ``pace=False``)."""
+        (explicit values are the replay/testing path, ``pace=False``).
+        ``recv_ns``: when a front door received the request
+        (``time.perf_counter_ns``), for the flight recorder's queue wait;
+        defaults to this call."""
         self._check_admitting()
+        if recv_ns is None:
+            recv_ns = time.perf_counter_ns()
         queue: asyncio.Queue = asyncio.Queue()
         fut = self._loop.create_future()
 
@@ -258,6 +271,7 @@ class AsyncServingEngine:
             except BaseException as e:   # bad params -> client error
                 self._resolve(fut, exc=e)
                 return
+            h.request.recv_ns = recv_ns
             ah = AsyncRequestHandle(h, self, queue)
             self._live[h.req_id] = (h, ah)
             self._resolve(fut, result=ah)
@@ -342,20 +356,25 @@ class AsyncServingEngine:
             pass
 
     def _run_control(self) -> None:
-        while True:
-            with self._cv:
-                if not self._control:
-                    return
-                fns = list(self._control)
-                self._control.clear()
-            for fn in fns:
-                fn()
+        with host_span(self.telemetry, HS_DRIVER_CONTROL):
+            while True:
+                with self._cv:
+                    if not self._control:
+                        return
+                    fns = list(self._control)
+                    self._control.clear()
+                for fn in fns:
+                    fn()
 
     def _deliver(self) -> None:
         """Drain each live sync handle's buffered events to its async twin
         on the event loop (driver thread only)."""
         if not self._live:
             return
+        with host_span(self.telemetry, HS_DRIVER_DELIVER):
+            self._deliver_live()
+
+    def _deliver_live(self) -> None:
         done: List[int] = []
         for rid, (h, ah) in self._live.items():
             evts = h.events()
@@ -380,14 +399,15 @@ class AsyncServingEngine:
                 if self._stop_requested:
                     break
                 if not engine.has_work:
-                    with self._cv:
+                    with self._cv, host_span(self.telemetry, HS_DRIVER_WAIT):
                         if not self._control and not self._stop_requested:
                             self._cv.wait()            # idle: park, no spin
                     continue
                 if self.pace:
                     ahead = engine.clock - self.engine_now()
                     if ahead > self._PACE_SLACK:
-                        with self._cv:
+                        with self._cv, host_span(self.telemetry,
+                                                 HS_DRIVER_WAIT):
                             if not self._control and not self._stop_requested:
                                 self._cv.wait(min(ahead, self._MAX_NAP))
                         continue
@@ -396,10 +416,23 @@ class AsyncServingEngine:
                 self._deliver()
 
             # -- drain phase: bounded by WALL seconds, streams stay live ----
+            end = time.monotonic() + self._drain_timeout
+
             def tick(_outcome) -> None:
                 self.steps += 1
                 self._run_control()    # disconnect aborts during drain
                 self._deliver()
+                if not self.pace:
+                    return
+                # a paced engine keeps tracking the wall while it drains:
+                # stepping flat out would fast-forward the simulation and
+                # flood the open streams with tokens no client waited for
+                ahead = min(engine.clock - self.engine_now(),
+                            end - time.monotonic())
+                if ahead > self._PACE_SLACK:
+                    with self._cv, host_span(self.telemetry, HS_DRIVER_WAIT):
+                        if not self._control:
+                            self._cv.wait(ahead)
 
             unfinished = engine.drain_wallclock(
                 self._drain_timeout, owner=self.name, on_step=tick)

@@ -34,6 +34,10 @@ from repro.serving.executor import (BatchPlan, Executor, RealExecutorAdapter,
                                     SimExecutor)
 from repro.serving.outputs import DriverClaim, OutputCollector, RequestHandle
 from repro.serving.schedulers import Scheduler, make_scheduler
+from repro.serving.telemetry import (HS_DUPLEXKV_PLAN, HS_ENGINE_COMMIT,
+                                     HS_ENGINE_SCHEDULE, HS_ENGINE_STEP,
+                                     HS_KV_D2H, HS_KV_H2D, HS_RUNNER_EXECUTE,
+                                     host_span)
 
 
 @dataclasses.dataclass
@@ -262,21 +266,27 @@ class EngineCore:
                 timing_cfg=cfg)
         else:
             self.executor = SimExecutor(cfg, hw, tp=tp, kv_dtype=kvd)
-        self.kv = DuplexKV(cfg, serving, hw)
-        if hasattr(self.executor, "bind"):
-            self.executor.bind(self.kv)   # pool-backed executors attach here
-        self.stats = EngineStats()
-        self.clock = 0.0
         # Flight recorder (DESIGN.md §Observability). Default off: no bus
         # is allocated and step() takes the golden-replay code path — every
-        # telemetry hook below is behind ``if self.telemetry is not None``.
+        # telemetry hook below is behind ``if self.telemetry is not None``
+        # (host spans of a core without a bus are the shared null span).
+        # An executor that runs the work on this host stamps the recorder
+        # on the host clock, from its spans, instead of the cost model's.
         self.replica_index = 0
         self.replica_role = "replica"
         self.telemetry = None
+        self._step_t0_ns = 0          # host clock at the open step's start
         if getattr(serving, "telemetry", False):
             from repro.serving.telemetry import TelemetryBus
             self.telemetry = TelemetryBus(
                 capacity=getattr(serving, "telemetry_buffer", 65536))
+            if getattr(self.executor, "host_clock", False):
+                self.telemetry.clock = "host"
+        self.kv = DuplexKV(cfg, serving, hw)
+        if hasattr(self.executor, "bind"):   # pool-backed executors attach
+            self.executor.bind(self.kv, telemetry=self.telemetry)
+        self.stats = EngineStats()
+        self.clock = 0.0
         self._exec_ema = 0.03   # for auto B_xfer sizing
         # Cross-iteration two-stage pipeline (ServingConfig.pipeline): the
         # per-direction transfer channels persist across step() calls and
@@ -347,6 +357,8 @@ class EngineCore:
             slo_class=slo_class,
             sampling=sp,
             prompt_ids=prompt_ids)
+        if self.telemetry is not None:   # receipt; a front door stamps first
+            req.recv_ns = time.perf_counter_ns()
         return self.submit(req, make_handle=True)
 
     def submit(self, req: Request, *, make_handle: bool = False
@@ -393,9 +405,11 @@ class EngineCore:
         self.executor.drop(req_id)
         r.finish_at(self.clock, reason=FINISH_ABORTED)
         if self.telemetry is not None:
-            self.telemetry.span("FINISH", req_id, self.clock, self.clock,
-                                slo_class=r.slo_class, reason=FINISH_ABORTED,
-                                tokens=r.tokens_generated)
+            now = self._recorder_now()
+            self.telemetry.record("FINISH", req_id, now, now,
+                                  slo_class=r.slo_class,
+                                  reason=FINISH_ABORTED,
+                                  tokens=r.tokens_generated)
         del self._index[req_id]
         self.stats.aborted += 1
         self.collector.dispatch([r.make_output(self.clock)])
@@ -511,6 +525,19 @@ class EngineCore:
     # ------------------------------------------------------------- iteration
     def step(self) -> IterationOutcome:
         """Run exactly one engine iteration at the current clock."""
+        tel = self.telemetry
+        if tel is None:
+            return self._step()
+        with tel.span(HS_ENGINE_STEP) as sp:
+            tel.begin_iteration()
+            self._step_t0_ns = sp.t0_ns
+            return self._step()
+
+    def _span(self, name: str):
+        """A host span on this core's bus (the null span without one)."""
+        return host_span(self.telemetry, name)
+
+    def _step(self) -> IterationOutcome:
         t = self.clock
         self._ingest(t)
         if not self.active:
@@ -519,45 +546,50 @@ class EngineCore:
             self._pipe_warm = False   # pipeline drains across an idle gap
             return IterationOutcome(t_start=t, t_end=self.clock, idle=True)
 
-        # -- schedule --------------------------------------------------------
-        bs = self.serving.block_size
-        b_xfer = None
-        if self.serving.auto_b_xfer:
-            # size the per-iteration transfer budget to what the duplex
-            # link can hide under model execution (§4.2.3 co-design)
-            rate = self.kv.engine.sustained_block_rate(
-                self.kv.block_bytes, self.kv.table.segments_per_block)
-            b_xfer = max(int(rate * self._exec_ema), 1)
-        kv_view = (self.kv.scheduler_view(self.active)
-                   if self._prefix_cache else None)
-        decision = self.scheduler.schedule(
-            self.active, t, self.kv.hbm_free_blocks, bs, b_xfer=b_xfer,
-            kv_view=kv_view)
+        with self._span(HS_ENGINE_SCHEDULE):
+            # -- schedule ----------------------------------------------------
+            bs = self.serving.block_size
+            b_xfer = None
+            if self.serving.auto_b_xfer:
+                # size the per-iteration transfer budget to what the duplex
+                # link can hide under model execution (§4.2.3 co-design)
+                rate = self.kv.engine.sustained_block_rate(
+                    self.kv.block_bytes, self.kv.table.segments_per_block)
+                b_xfer = max(int(rate * self._exec_ema), 1)
+            kv_view = (self.kv.scheduler_view(self.active)
+                       if self._prefix_cache else None)
+            decision = self.scheduler.schedule(
+                self.active, t, self.kv.hbm_free_blocks, bs, b_xfer=b_xfer,
+                kv_view=kv_view)
 
-        # -- admission / preemption (same residency snapshot as the
-        # scheduler, so the two layers' block accounting cannot drift) ------
-        adm = self.admission.apply(decision, kv_view=kv_view, t=t)
+            # -- admission / preemption (same residency snapshot as the
+            # scheduler, so the two layers' block accounting cannot drift) --
+            adm = self.admission.apply(decision, kv_view=kv_view, t=t)
 
-        # -- build device batch ---------------------------------------------
-        plan = self.batcher.build(self.active, adm, t)
+            # -- build device batch -----------------------------------------
+            plan = self.batcher.build(self.active, adm, t)
 
-        # stall-breaker: cache-hit blocks pinned at ingest by still-waiting
-        # requests are neither evictable (refcount > 0) nor preemptible (no
-        # running owner). If an iteration schedules nothing at all while
-        # such pins exist, they may be starving admission of the very blocks
-        # it needs — un-pin them; the requests retry uncached next step.
-        if (self._prefix_cache and plan.empty and not adm.started
-                and not adm.swapin_ids and not adm.preempt_ids):
-            for r in self.active:
-                if (r.state == RequestState.WAITING and r.num_cached_tokens
-                        and r.prefill_pos == r.num_cached_tokens):
-                    self.kv.drop_prefix_refs(r.req_id)
-                    r.num_cached_tokens = 0
-                    r.prefill_pos = 0
-        # budgeted-but-unstarted requests (chunk budget exhausted, OOB) stay
-        # WAITING and are not admissions; they retry next iteration
-        admitted = [r.req_id for r in adm.started
-                    if r.state == RequestState.RUNNING]
+            # stall-breaker: cache-hit blocks pinned at ingest by
+            # still-waiting requests are neither evictable (refcount > 0)
+            # nor preemptible (no running owner). If an iteration schedules
+            # nothing at all while such pins exist, they may be starving
+            # admission of the very blocks it needs — un-pin them; the
+            # requests retry uncached next step.
+            if (self._prefix_cache and plan.empty and not adm.started
+                    and not adm.swapin_ids and not adm.preempt_ids):
+                for r in self.active:
+                    if (r.state == RequestState.WAITING
+                            and r.num_cached_tokens
+                            and r.prefill_pos == r.num_cached_tokens):
+                        self.kv.drop_prefix_refs(r.req_id)
+                        r.num_cached_tokens = 0
+                        r.prefill_pos = 0
+            # budgeted-but-unstarted requests (chunk budget exhausted, OOB)
+            # stay WAITING and are not admissions; they retry next iteration
+            admitted = [r.req_id for r in adm.started
+                        if r.state == RequestState.RUNNING]
+        if self.telemetry is not None and admitted:
+            self._stamp_admitted(adm.started, admitted)
 
         # -- execute + transfer (pipelined or serial) -----------------------
         exec_s = self.executor.step_time(plan)
@@ -566,9 +598,10 @@ class EngineCore:
         # WRITE this iteration (a logically-synced tail block's last token
         # lands physically now — see blocktable.eager_candidates)
         plan_rows = self._plan_rows(plan) if self._pipeline else None
-        xfers = self.kv.plan_iteration(
-            adm.preempt_ids, adm.swapin_ids, iteration_budget_s=exec_s,
-            exclude_slots=plan_rows[1] if plan_rows else frozenset())
+        with self._span(HS_DUPLEXKV_PLAN):
+            xfers = self.kv.plan_iteration(
+                adm.preempt_ids, adm.swapin_ids, iteration_budget_s=exec_s,
+                exclude_slots=plan_rows[1] if plan_rows else frozenset())
         self.stats.schedule_ms += self.executor.plan_time(plan) * 1e3
         tr_s = xfers.stats.e2e_time
         eager_d2h = xfers.eager_stats.d2h_time if xfers.eager_stats else 0.0
@@ -654,15 +687,38 @@ class EngineCore:
         # guard — carried eager D2H may only RACE reads) and dispatches
         # through execute_async: every launch enqueues without a host sync
         # and wait() is the iteration's single sync point.
-        if self._pipeline:
-            self.kv.table.set_compute_rows(*plan_rows)
-            try:
-                result = self.executor.execute_async(plan, self._index).wait()
-            finally:
-                self.kv.table.clear_compute_rows()
-        else:
-            result = self.executor.execute(plan, self._index)
+        with self._span(HS_RUNNER_EXECUTE):
+            if self._pipeline:
+                self.kv.table.set_compute_rows(*plan_rows)
+                try:
+                    result = self.executor.execute_async(
+                        plan, self._index).wait()
+                finally:
+                    self.kv.table.clear_compute_rows()
+            else:
+                result = self.executor.execute(plan, self._index)
 
+        with self._span(HS_ENGINE_COMMIT):
+            outputs, finished = self._commit(plan, result)
+            if self.telemetry is not None:
+                self._record_telemetry(t, adm, plan, xfers, eager_d2h,
+                                       admitted, resumed, finished, tel_w)
+        for rid in finished:
+            self._index.pop(rid, None)
+        self.active = [r for r in self.active
+                       if r.state != RequestState.FINISHED]
+
+        return IterationOutcome(
+            t_start=t, t_end=self.clock, exec_s=exec_s, transfer_s=tr_s,
+            plan=plan, admitted=admitted, resumed=resumed,
+            preempted=adm.preempt_ids, finished=finished, outputs=outputs)
+
+    def _commit(self, plan: BatchPlan, result
+                ) -> Tuple[List[RequestOutput], List[int]]:
+        """Emit the iteration's tokens: advance prefill and decode progress,
+        finish what is done and dispatch the streaming outputs. Returns the
+        outputs and the finished req_ids."""
+        stamp = self.telemetry is not None
         new_count: Dict[int, int] = {}        # req_id -> tokens this iter
         new_ids: Dict[int, List[int]] = {}    # req_id -> their ids (real mode)
 
@@ -681,6 +737,8 @@ class EngineCore:
                 if rid in result.tokens:
                     emit_token(r, result.tokens[rid])
                 r.record_token(self.clock)    # first token at prefill tail
+                if stamp:
+                    r.first_token_ns = time.perf_counter_ns()
                 new_count[rid] = new_count.get(rid, 0) + 1
             self.kv.sync_progress(r.req_id, r.prefill_pos,
                                   written_from=r.prefill_pos - take)
@@ -713,20 +771,45 @@ class EngineCore:
                                  new_ids.get(r.req_id))
                    for r in self.active if r.req_id in new_count]
         self.collector.dispatch(outputs)
-        if self.telemetry is not None:
-            self._record_telemetry(t, adm, plan, xfers, eager_d2h,
-                                   admitted, resumed, finished, tel_w)
-        for rid in finished:
-            self._index.pop(rid, None)
-        self.active = [r for r in self.active
-                       if r.state != RequestState.FINISHED]
-
-        return IterationOutcome(
-            t_start=t, t_end=self.clock, exec_s=exec_s, transfer_s=tr_s,
-            plan=plan, admitted=admitted, resumed=resumed,
-            preempted=adm.preempt_ids, finished=finished, outputs=outputs)
+        return outputs, finished
 
     # -------------------------------------------------------------- telemetry
+    def _stamp_admitted(self, started: List[Request],
+                        admitted: List[int]) -> None:
+        """Host-clock admission stamps, and each admitted request's queue
+        wait (admit - recv) on the bus's counter."""
+        now = time.perf_counter_ns()
+        ids = set(admitted)
+        for r in started:
+            if r.req_id in ids and r.admit_ns is None:
+                r.admit_ns = now
+                if r.recv_ns is not None:
+                    self.telemetry.count_queue_wait(now - r.recv_ns)
+
+    def _recorder_now(self) -> float:
+        """Now, on the flight recorder's clock."""
+        if self.telemetry.clock == "host":
+            return time.perf_counter_ns() * 1e-9
+        return self.clock
+
+    def _host_windows(self
+                      ) -> Tuple[Dict[str, float], float, float, float]:
+        """This iteration's windows from its host spans (seconds on the
+        host clock), in the shape ``_record_telemetry`` takes, plus the
+        scheduling, D2H and H2D busy seconds."""
+        spans = self.telemetry.iteration_windows()
+        t0 = self._step_t0_ns * 1e-9
+
+        def win(name: str) -> Tuple[float, float]:
+            s = spans.get(name)
+            return (s[0] * 1e-9, s[2] * 1e-9) if s else (t0, 0.0)
+
+        ex, d2h, h2d = (win(HS_RUNNER_EXECUTE), win(HS_KV_D2H),
+                        win(HS_KV_H2D))
+        w = dict(exec_start=ex[0], exec_dur=ex[1], d2h_start=d2h[0],
+                 h2d_start=h2d[0], overlap=0.0, stall=0.0, hidden=0.0)
+        return w, win(HS_ENGINE_SCHEDULE)[1], d2h[1], h2d[1]
+
     def _record_telemetry(self, t: float, adm: AdmissionOutcome,
                           plan: BatchPlan, xfers, eager_d2h: float,
                           admitted: List[int], resumed: List[int],
@@ -734,19 +817,29 @@ class EngineCore:
         """Record this iteration on the flight recorder: one EngineEvent
         (execution + per-direction channel windows) plus the request
         lifecycle spans it produced. Called only when the bus exists;
-        append-only side records — nothing here feeds back into the sim."""
+        append-only side records — nothing here feeds back into the sim.
+        On the host clock the windows are the iteration's host spans and
+        the lifecycle spans take the requests' host stamps; cost-model
+        fields with no host counterpart are recorded as 0."""
         from repro.core.vlt import vlt
         tel = self.telemetry
+        host = tel.clock == "host"
         bb = self.kv.block_bytes
         eager_bytes = xfers.eager_stats.d2h_bytes if xfers.eager_stats else 0
-        d2h_busy = xfers.stats.d2h_time + eager_d2h
-        h2d_busy = xfers.stats.h2d_time
+        if host:
+            w, sched_s, d2h_busy, h2d_busy = self._host_windows()
+            t_start, t_end = self._step_t0_ns * 1e-9, self._recorder_now()
+        else:
+            sched_s = self.executor.plan_time(plan)
+            d2h_busy = xfers.stats.d2h_time + eager_d2h
+            h2d_busy = xfers.stats.h2d_time
+            t_start, t_end = t, self.clock
         tel.event(
-            iteration=self.stats.iterations, t_start=t, t_end=self.clock,
+            iteration=self.stats.iterations, t_start=t_start, t_end=t_end,
             exec_start=w["exec_start"], exec_s=w["exec_dur"],
             d2h_start=w["d2h_start"], d2h_s=d2h_busy,
             h2d_start=w["h2d_start"], h2d_s=h2d_busy,
-            sched_s=self.executor.plan_time(plan),
+            sched_s=sched_s,
             overlap_s=w["overlap"], stall_s=w["stall"],
             plan_hidden_s=w["hidden"],
             attrs=dict(
@@ -764,48 +857,66 @@ class EngineCore:
         admitted_set = set(admitted)
         for r in adm.started:
             if r.req_id in admitted_set:
-                tel.span("ADMIT", r.req_id, r.arrival_time, t,
-                         slo_class=r.slo_class,
-                         queue_wait_s=t - r.arrival_time)
+                if host:
+                    t_in = (r.recv_ns or r.admit_ns) * 1e-9
+                    t_adm = r.admit_ns * 1e-9
+                else:
+                    t_in, t_adm = r.arrival_time, t
+                tel.record("ADMIT", r.req_id, t_in, t_adm,
+                           slo_class=r.slo_class, queue_wait_s=t_adm - t_in)
         for rid, take in plan.prefill_chunks:
             r = self._by_id(rid)
             if r is not None:
-                tel.span("PREFILL", rid, w["exec_start"],
-                         w["exec_start"] + w["exec_dur"],
-                         slo_class=r.slo_class, tokens=take,
-                         pos=r.prefill_pos)
+                tel.record("PREFILL", rid, w["exec_start"],
+                           w["exec_start"] + w["exec_dur"],
+                           slo_class=r.slo_class, tokens=take,
+                           pos=r.prefill_pos)
         for rid in plan.decode_reqs:
             r = self._by_id(rid)
             if r is not None:
-                tel.span("DECODE", rid, w["exec_start"],
-                         w["exec_start"] + w["exec_dur"],
-                         slo_class=r.slo_class,
-                         tokens_generated=r.tokens_generated)
+                tel.record("DECODE", rid, w["exec_start"],
+                           w["exec_start"] + w["exec_dur"],
+                           slo_class=r.slo_class,
+                           tokens_generated=r.tokens_generated)
         for rid in adm.preempt_ids:
             r = self._by_id(rid)
             if r is not None:
-                tel.span("ROTATE_OUT", rid, w["d2h_start"],
-                         w["d2h_start"] + d2h_busy,
-                         slo_class=r.slo_class, direction="d2h",
-                         bytes=len(self.kv.table.blocks_of(rid)) * bb)
+                tel.record("ROTATE_OUT", rid, w["d2h_start"],
+                           w["d2h_start"] + d2h_busy,
+                           slo_class=r.slo_class, direction="d2h",
+                           bytes=len(self.kv.table.blocks_of(rid)) * bb)
         for rid in resumed:
             r = self._by_id(rid)
             if r is not None:
-                tel.span("ROTATE_IN", rid, w["h2d_start"],
-                         w["h2d_start"] + h2d_busy,
-                         slo_class=r.slo_class, direction="h2d",
-                         bytes=len(self.kv.table.blocks_of(rid)) * bb)
+                tel.record("ROTATE_IN", rid, w["h2d_start"],
+                           w["h2d_start"] + h2d_busy,
+                           slo_class=r.slo_class, direction="h2d",
+                           bytes=len(self.kv.table.blocks_of(rid)) * bb)
         for rid in finished:
             r = self._by_id(rid)
             if r is not None:
                 attrs = dict(reason=r.finish_reason,
                              tokens=r.tokens_generated,
                              rotations=r.rotations, migrations=r.migrations)
-                bd = r.ttft_breakdown()
+                bd = (self._host_ttft_breakdown(r) if host
+                      else r.ttft_breakdown())
                 if bd is not None:
                     attrs.update(bd)
-                tel.span("FINISH", rid, self.clock, self.clock,
-                         slo_class=r.slo_class, **attrs)
+                tel.record("FINISH", rid, t_end, t_end,
+                           slo_class=r.slo_class, **attrs)
+
+    @staticmethod
+    def _host_ttft_breakdown(r: Request) -> Optional[Dict[str, float]]:
+        """``Request.ttft_breakdown`` from the host stamps. Rotation stall
+        has no host stamp and reads 0; the remainder is admit to first
+        token."""
+        if r.recv_ns is None or r.admit_ns is None \
+                or r.first_token_ns is None:
+            return None
+        return {"ttft_s": (r.first_token_ns - r.recv_ns) * 1e-9,
+                "queue_wait_s": (r.admit_ns - r.recv_ns) * 1e-9,
+                "rotation_stall_s": 0.0,
+                "prefill_compute_s": (r.first_token_ns - r.admit_ns) * 1e-9}
 
     # ------------------------------------------------------------------ utils
     def _plan_rows(self, plan: BatchPlan) -> Tuple[Set[int], Set[int]]:

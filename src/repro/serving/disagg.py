@@ -303,14 +303,14 @@ class DisaggCluster:
         r.begin_migration()
         dst.adopt_request(r, arrival_time=rec.t_ready)
         if src.telemetry is not None:
-            src.telemetry.span(
+            src.telemetry.record(
                 "MIGRATE", r.req_id, rec.t_start, rec.t_ready,
                 slo_class=r.slo_class, direction="d2h",
                 bytes=rec.nbytes, d2h_bytes=rec.d2h_bytes,
                 blocks=rec.blocks, dst_replica=dst.replica_index,
                 shared_on_target=rec.shared_on_target)
         if dst.telemetry is not None:
-            dst.telemetry.span(
+            dst.telemetry.record(
                 "MIGRATE", r.req_id, rec.t_start, rec.t_ready,
                 slo_class=r.slo_class, direction="h2d",
                 bytes=rec.nbytes, blocks=rec.blocks,

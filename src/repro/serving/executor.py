@@ -81,6 +81,10 @@ class Executor:
     """
 
     supports_prefix_cache = True
+    # True where ``execute`` runs the iteration's work on this host and
+    # times it with host spans: the engine's flight recorder then stamps
+    # the host clock instead of the cost model's (DESIGN.md §Observability)
+    host_clock = False
 
     def step_time(self, plan: BatchPlan) -> float:
         raise NotImplementedError

@@ -38,7 +38,6 @@ makes that choice for every launch. See DESIGN.md §Execution layer.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -47,6 +46,10 @@ from repro.configs.base import (GH200, HardwareProfile, ModelConfig,
                                 ServingConfig)
 from repro.serving.executor import (ExecutionResult, Executor,
                                     PendingExecution, SimExecutor)
+from repro.serving.telemetry import (HS_KV_D2H, HS_KV_D2H_READBACK,
+                                     HS_KV_H2D, HS_KV_H2D_STAGE,
+                                     HS_RUNNER_LAUNCH, HS_RUNNER_PREPARE,
+                                     HS_RUNNER_SYNC, host_span)
 
 
 def _pow2(n: int) -> int:
@@ -95,7 +98,7 @@ class PagedKVStore:
     def __init__(self, cfg: ModelConfig, serving: ServingConfig, dtype,
                  *, staging: int = 64,
                  double_buffer: bool = False, tp_plan=None, mesh=None,
-                 kv_dtype: str = "bf16"):
+                 kv_dtype: str = "bf16", telemetry=None):
         import jax
         import jax.numpy as jnp
         if staging < 1 or staging & (staging - 1):
@@ -179,11 +182,8 @@ class PagedKVStore:
         self.d2d_rows = 0
         self.d2h_rows = 0
         self.h2d_rows = 0
-        # wall-clock seconds spent DISPATCHING kernel launches (async
-        # enqueue cost, host side). Observability only — never fed back
-        # into the sim clock, which stays the model's timing authority.
-        self.copy_launch_wall_s = 0.0
-        self.upload_launch_wall_s = 0.0
+        # the engine's flight recorder (host spans), None when it is off
+        self.telemetry = telemetry
 
         from repro.kernels.kv_copy import kv_copy_tpu
 
@@ -271,16 +271,12 @@ class PagedKVStore:
         s = np.full(np2, -1, np.int32)
         d = np.full(np2, self.trash_row, np.int32)
         s[:n], d[:n] = src, dst
-        import jax
-        t0 = time.perf_counter()
-        with jax.named_scope("superinfer.kv_copy"):
-            if self.quantized:
-                self.pool, self.scales = self._jit_copy_q(
-                    self.pool, self.scales, jnp.asarray(s), jnp.asarray(d))
-            else:
-                self.pool = self._jit_copy(self.pool, jnp.asarray(s),
-                                           jnp.asarray(d))
-        self.copy_launch_wall_s += time.perf_counter() - t0
+        if self.quantized:
+            self.pool, self.scales = self._jit_copy_q(
+                self.pool, self.scales, jnp.asarray(s), jnp.asarray(d))
+        else:
+            self.pool = self._jit_copy(self.pool, jnp.asarray(s),
+                                       jnp.asarray(d))
         self.copy_launches += 1
 
     # -- DuplexKV data-backend protocol ------------------------------------
@@ -296,11 +292,13 @@ class PagedKVStore:
         host sync on the pool — in double-buffer mode this is deferred one
         chunk so the next gather launch is already in the dispatch queue."""
         n = len(chunk)
-        data = np.asarray(self.pool[base:base + n])
-        if self.quantized:
+        with host_span(self.telemetry, HS_KV_D2H_READBACK):
+            data = np.asarray(self.pool[base:base + n])
             # the host tier stores (int8 row, fp32 scale row) — the D2H
             # transfer the DuplexKV timed is the ~half-size int8 payload
-            sdata = np.asarray(self.scales[base:base + n])
+            sdata = (np.asarray(self.scales[base:base + n])
+                     if self.quantized else None)
+        if self.quantized:
             for j, d in enumerate(chunk):
                 self.host[d.dst_slot] = (np.array(data[j]),
                                          np.array(sdata[j]))
@@ -314,6 +312,10 @@ class PagedKVStore:
         ``kv_copy_tpu`` launch), then ONE contiguous device->host copy.
         Double-buffer mode alternates two gather buffers, reading chunk
         i-1 back only after chunk i's gather is dispatched."""
+        with host_span(self.telemetry, HS_KV_D2H):
+            self._run_d2h(descs)
+
+    def _run_d2h(self, descs) -> None:
         q = self.d2h_chunk
         pending = None                      # (base, chunk) awaiting readback
         for i in range(0, len(descs), q):
@@ -335,6 +337,10 @@ class PagedKVStore:
         """Host tier -> device rows: one contiguous host->device upload into
         staging (the H2D half, in double-buffer mode), then a batched
         ``kv_copy_tpu`` scatter into place."""
+        with host_span(self.telemetry, HS_KV_H2D):
+            self._run_h2d(descs)
+
+    def _run_h2d(self, descs) -> None:
         import jax.numpy as jnp
         for i in range(0, len(descs), self.h2d_chunk):
             chunk = descs[i:i + self.h2d_chunk]
@@ -348,28 +354,22 @@ class PagedKVStore:
                         f"{d.src_slot} holds no data (lost copy)")
                 rows.append(row)
             np2 = _pow2(n)
-            import jax
-            t0 = time.perf_counter()
-            with jax.named_scope("superinfer.kv_upload"):
+            with host_span(self.telemetry, HS_KV_H2D_STAGE):
+                vals = [r[0] for r in rows] if self.quantized else rows
+                buf = np.zeros((np2,) + self.row_shape, vals[0].dtype)
+                buf[:n] = np.stack(vals)
                 if self.quantized:
-                    vals = [r[0] for r in rows]
-                    srows = [r[1] for r in rows]
-                    buf = np.zeros((np2,) + self.row_shape, vals[0].dtype)
-                    buf[:n] = np.stack(vals)
                     sbuf = np.zeros((np2,) + self.scale_row_shape,
                                     np.float32)
-                    sbuf[:n] = np.stack(srows)
-                    self.pool, self.scales = self._jit_upload_q(
-                        self.pool, self.scales, jnp.asarray(buf),
-                        jnp.asarray(sbuf),
-                        jnp.asarray(self.h2d_base, np.int32))
-                else:
-                    buf = np.zeros((np2,) + self.row_shape, rows[0].dtype)
-                    buf[:n] = np.stack(rows)
-                    self.pool = self._jit_upload(
-                        self.pool, jnp.asarray(buf),
-                        jnp.asarray(self.h2d_base, np.int32))
-            self.upload_launch_wall_s += time.perf_counter() - t0
+                    sbuf[:n] = np.stack([r[1] for r in rows])
+            if self.quantized:
+                self.pool, self.scales = self._jit_upload_q(
+                    self.pool, self.scales, jnp.asarray(buf),
+                    jnp.asarray(sbuf), jnp.asarray(self.h2d_base, np.int32))
+            else:
+                self.pool = self._jit_upload(
+                    self.pool, jnp.asarray(buf),
+                    jnp.asarray(self.h2d_base, np.int32))
             self._copy_rows(list(range(self.h2d_base, self.h2d_base + n)),
                             [d.dst_slot for d in chunk])
             self.h2d_rows += n
@@ -388,6 +388,7 @@ class PagedModelRunner(Executor):
     """
 
     supports_prefix_cache = True
+    host_clock = True
 
     def __init__(self, model_cfg: ModelConfig, serving: ServingConfig,
                  hw: HardwareProfile = GH200, *, seed: int = 0,
@@ -435,6 +436,7 @@ class PagedModelRunner(Executor):
         self._layers = _unstack_layers(lm.program, params.pop("segments"))
         self.store: Optional[PagedKVStore] = None
         self.kv = None
+        self.telemetry = None          # the engine's bus, given at bind
         # psum flags are trace-time constants: at tp == 1 neither branch is
         # taken, so the jaxpr — and the golden replay — is bit-identical to
         # the single-chip runner
@@ -514,21 +516,20 @@ class PagedModelRunner(Executor):
         self.decode_tokens = 0
         self.attn_launches = 0
         self.prefill_chunks_run = 0
-        # host-side dispatch wall time per launch family (observability
-        # only; the sim clock never reads these)
-        self.prefill_launch_wall_s = 0.0
-        self.decode_launch_wall_s = 0.0
 
     # ------------------------------------------------------------- binding
-    def bind(self, kv) -> None:
+    def bind(self, kv, telemetry=None) -> None:
         """Attach to the engine's DuplexKV: allocate the device pool sized
-        to its block table and register as the physical data backend."""
+        to its block table and register as the physical data backend.
+        ``telemetry``: the engine's flight recorder, whose host spans the
+        runner and its store then record."""
         self.kv = kv
+        self.telemetry = telemetry
         self.store = PagedKVStore(
             self.cfg, self.serving, self.dtype,
             double_buffer=bool(getattr(self.serving, "pipeline", False)),
             tp_plan=None if self.tp_plan.trivial else self.tp_plan,
-            mesh=self.mesh, kv_dtype=self.kv_dtype)
+            mesh=self.mesh, kv_dtype=self.kv_dtype, telemetry=telemetry)
         kv.attach_data_backend(self.store)
 
     # ------------------------------------------------------ executor protocol
@@ -590,7 +591,8 @@ class PagedModelRunner(Executor):
 
         def waiter() -> ExecutionResult:
             out = ExecutionResult()
-            toks, arr = jax.device_get(([t for _, t in pre], nxt))
+            with host_span(self.telemetry, HS_RUNNER_SYNC):
+                toks, arr = jax.device_get(([t for _, t in pre], nxt))
             for (rid, _), tok in zip(pre, toks):
                 out.tokens[rid] = int(tok)
             if arr is not None:
@@ -632,77 +634,74 @@ class PagedModelRunner(Executor):
         take = min(take, r.prompt_len - start)
         if take <= 0:
             return None
-        ids = r.prompt_ids[start:start + take]
-        rows = self._rows(r.req_id)
-        nb_ctx = _cdiv(start + take, P)
-        if len(rows) < nb_ctx:
-            raise RuntimeError(
-                f"req {r.req_id}: {len(rows)} blocks assigned, prefill "
-                f"needs {nb_ctx}")
-        tp, mbp = _pow2(take), _pow2(nb_ctx)
-        ids_p = np.zeros(tp, np.int32)
-        ids_p[:take] = ids
-        rows_p = np.full(mbp, self.store.trash_row, np.int32)
-        rows_p[:min(len(rows), mbp)] = rows[:mbp]
-        import jax
-        t0 = time.perf_counter()
-        with jax.named_scope("superinfer.prefill_chunk"):
+        with host_span(self.telemetry, HS_RUNNER_PREPARE):
+            ids = r.prompt_ids[start:start + take]
+            rows = self._rows(r.req_id)
+            nb_ctx = _cdiv(start + take, P)
+            if len(rows) < nb_ctx:
+                raise RuntimeError(
+                    f"req {r.req_id}: {len(rows)} blocks assigned, prefill "
+                    f"needs {nb_ctx}")
+            tp, mbp = _pow2(take), _pow2(nb_ctx)
+            ids_p = np.zeros(tp, np.int32)
+            ids_p[:take] = ids
+            rows_p = np.full(mbp, self.store.trash_row, np.int32)
+            rows_p[:min(len(rows), mbp)] = rows[:mbp]
+            args = (jnp.asarray(ids_p), jnp.asarray(start, jnp.int32),
+                    jnp.asarray(take, jnp.int32), jnp.asarray(rows_p))
+        with host_span(self.telemetry, HS_RUNNER_LAUNCH):
             if self.quantized:
                 self.store.pool, self.store.scales, tok = self._jit_prefill(
                     self._layers, self._head, self.store.pool,
-                    self.store.scales,
-                    jnp.asarray(ids_p), jnp.asarray(start, jnp.int32),
-                    jnp.asarray(take, jnp.int32), jnp.asarray(rows_p))
+                    self.store.scales, *args)
             else:
                 self.store.pool, tok = self._jit_prefill(
-                    self._layers, self._head, self.store.pool,
-                    jnp.asarray(ids_p), jnp.asarray(start, jnp.int32),
-                    jnp.asarray(take, jnp.int32), jnp.asarray(rows_p))
-        self.prefill_launch_wall_s += time.perf_counter() - t0
+                    self._layers, self._head, self.store.pool, *args)
         self.prefill_chunks_run += 1
         if start + take >= r.prompt_len and r.tokens_generated == 0:
-            return tok if defer else int(tok)   # defer: device array, no sync
+            if defer:
+                return tok                  # device array, no sync
+            with host_span(self.telemetry, HS_RUNNER_SYNC):
+                return int(tok)
         return None
 
     def _run_decode_batch(self, dec, defer: bool = False):
         import jax.numpy as jnp
         P = self.serving.block_size
-        cls = [r.total_len - 1 for r in dec]
-        rows = [self._rows(r.req_id) for r in dec]
-        for r, cl, rw in zip(dec, cls, rows):
-            if len(rw) < _cdiv(cl + 1, P):
-                raise RuntimeError(
-                    f"req {r.req_id}: {len(rw)} blocks assigned, decode at "
-                    f"context {cl + 1} needs {_cdiv(cl + 1, P)}")
-        mbp = _pow2(max(_cdiv(cl + 1, P) for cl in cls))
-        bp = _pow2(len(dec))
-        toks = np.zeros(bp, np.int32)
-        cl_p = np.zeros(bp, np.int32)
-        bt = np.full((bp, mbp), self.store.trash_row, np.int32)
-        for i, r in enumerate(dec):
-            toks[i] = r.generated_ids[-1]
-            cl_p[i] = cls[i]
-            k = min(len(rows[i]), mbp)
-            bt[i, :k] = rows[i][:k]
-        import jax
-        t0 = time.perf_counter()
-        with jax.named_scope("superinfer.paged_decode"):
+        with host_span(self.telemetry, HS_RUNNER_PREPARE):
+            cls = [r.total_len - 1 for r in dec]
+            rows = [self._rows(r.req_id) for r in dec]
+            for r, cl, rw in zip(dec, cls, rows):
+                if len(rw) < _cdiv(cl + 1, P):
+                    raise RuntimeError(
+                        f"req {r.req_id}: {len(rw)} blocks assigned, decode "
+                        f"at context {cl + 1} needs {_cdiv(cl + 1, P)}")
+            mbp = _pow2(max(_cdiv(cl + 1, P) for cl in cls))
+            bp = _pow2(len(dec))
+            toks = np.zeros(bp, np.int32)
+            cl_p = np.zeros(bp, np.int32)
+            bt = np.full((bp, mbp), self.store.trash_row, np.int32)
+            for i, r in enumerate(dec):
+                toks[i] = r.generated_ids[-1]
+                cl_p[i] = cls[i]
+                k = min(len(rows[i]), mbp)
+                bt[i, :k] = rows[i][:k]
+            args = (jnp.asarray(toks), jnp.asarray(bt), jnp.asarray(cl_p))
+        with host_span(self.telemetry, HS_RUNNER_LAUNCH):
             if self.quantized:
                 self.store.pool, self.store.scales, nxt = self._jit_decode(
                     self._layers, self._head, self.store.pool,
-                    self.store.scales,
-                    jnp.asarray(toks), jnp.asarray(bt), jnp.asarray(cl_p))
+                    self.store.scales, *args)
             else:
                 self.store.pool, nxt = self._jit_decode(
-                    self._layers, self._head, self.store.pool,
-                    jnp.asarray(toks), jnp.asarray(bt), jnp.asarray(cl_p))
-        self.decode_launch_wall_s += time.perf_counter() - t0
+                    self._layers, self._head, self.store.pool, *args)
         self.decode_batches += 1
         self.decode_tokens += len(dec)
         self.attn_launches += len(self._layers)
         if defer:
             return nxt                          # device array, no host sync
-        nxt = np.asarray(nxt)
+        with host_span(self.telemetry, HS_RUNNER_SYNC):
+            nxt = np.asarray(nxt)
         return {r.req_id: int(nxt[i]) for i, r in enumerate(dec)}
 
     # ------------------------------------------------------- jitted kernels

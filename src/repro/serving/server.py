@@ -62,6 +62,7 @@ REQUEST_TIMEOUT_S = 30.0
 # object per stderr line — the single emitter shared with serve.py and the
 # launcher supervisor (re-exported here; see telemetry.StructuredLogger).
 from repro.serving.telemetry import log_event  # noqa: E402  (re-export)
+from repro.serving.telemetry import HS_HTTP_GENERATE, host_span  # noqa: E402
 
 
 # --------------------------------------------------------------------- config
@@ -377,9 +378,10 @@ class InferenceServer:
         process exit code: 0 clean drain, 1 if requests were cut off."""
         await self._shutdown_ev.wait()
         # 1) stop admitting: close the listener (readyz already flips 503
-        #    via _draining, so balancers stop routing before the close)
+        #    via _draining, so balancers stop routing before the close).
+        #    Not wait_closed() yet: it also waits for every open
+        #    connection (Python 3.12), the very streams the drain bounds.
         self._server.close()
-        await self._server.wait_closed()
         # 2) finish in-flight work bounded by WALL seconds; open streams
         #    keep receiving tokens while the engine drains
         unfinished = await self.service.shutdown(self.cfg.drain_timeout)
@@ -391,6 +393,7 @@ class InferenceServer:
             t.cancel()
         if self._conn_tasks:
             await asyncio.gather(*self._conn_tasks, return_exceptions=True)
+        await self._server.wait_closed()
         log_event("drain_done", unfinished=len(unfinished),
                   unfinished_ids=unfinished[:16])
         return 0 if not unfinished else 1
@@ -623,14 +626,16 @@ class InferenceServer:
         if self._draining:
             raise HttpError(503, "draining: not admitting new requests")
         kw = self._parse_generate(body)
-        try:
-            handle = await self.service.submit(**kw)
-        except ServiceDraining as e:
-            raise HttpError(503, str(e)) from e
-        except ServiceStopped as e:
-            raise HttpError(503, str(e)) from e
-        except (ValueError, KeyError, TypeError) as e:
-            raise HttpError(400, str(e)) from e
+        with host_span(self.service.telemetry, HS_HTTP_GENERATE):
+            try:
+                handle = await self.service.submit(
+                    **kw, recv_ns=time.perf_counter_ns())
+            except ServiceDraining as e:
+                raise HttpError(503, str(e)) from e
+            except ServiceStopped as e:
+                raise HttpError(503, str(e)) from e
+            except (ValueError, KeyError, TypeError) as e:
+                raise HttpError(400, str(e)) from e
 
         self.streams_started += 1
         self.streams_active += 1

@@ -1,16 +1,23 @@
 """Flight recorder: bounded ring-buffer telemetry for the serving engine.
 
-Three pieces, all stdlib-only:
+Three pieces, all stdlib-only (JAX is used only where a process already
+imported it):
 
 * ``TelemetryBus`` — per-replica ring buffers of typed request-lifecycle
   ``Span``s (ADMIT, PREFILL, DECODE, ROTATE_OUT, ROTATE_IN, MIGRATE,
   FINISH) and per-iteration ``EngineEvent``s (batch composition, VLT
   slack, HBM headroom, per-direction transfer-channel windows, pipeline
-  overlap/stall). All timestamps are SIM-CLOCK seconds — the same clock
-  every SLO number is computed on — so the trace is exact, not sampled.
-  The bus is default OFF (``ServingConfig.telemetry=False``): no bus is
-  allocated and the engine's step loop takes the byte-identical
-  golden-replay code path.
+  overlap/stall), plus named HOST SPANS (``TelemetryBus.span``) with
+  per-name counters and a host-clock queue-wait histogram. The simulator
+  stamps spans and events on the SIM CLOCK — the same clock every SLO
+  number is computed on. An executor that runs the work on this host
+  (``Executor.host_clock``: the paged runner) has them stamped from its
+  host spans instead (``clock == "host"``, ``time.perf_counter`` seconds),
+  and each host span also opens a ``jax.profiler.TraceAnnotation`` of the
+  same name, so in a profiler session it lands in the device trace's
+  timeline. The bus is default OFF (``ServingConfig.telemetry=False``): no
+  bus is allocated, every host span is one shared null context, and the
+  engine's step loop takes the byte-identical golden-replay code path.
 
 * ``StructuredLogger`` / ``log_event`` — the single JSON-lines emitter
   shared by the HTTP server, the launcher supervisor and ``serve.py``:
@@ -26,13 +33,17 @@ Three pieces, all stdlib-only:
 
 See DESIGN.md §Observability.
 """
+import bisect
+import contextvars
 import dataclasses
 import json
 import re
 import sys
+import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, List, Mapping, Optional, Sequence
+from typing import (Any, Dict, Iterable, List, Mapping, Optional, Sequence,
+                    Tuple)
 
 # ---------------------------------------------------------------- span kinds
 SPAN_ADMIT = "ADMIT"            # arrival -> first prefill chunk scheduled
@@ -49,7 +60,7 @@ SPAN_KINDS = (SPAN_ADMIT, SPAN_PREFILL, SPAN_DECODE, SPAN_ROTATE_OUT,
 
 @dataclasses.dataclass(frozen=True)
 class Span:
-    """One request-lifecycle interval, stamped with sim-clock start/end."""
+    """One request-lifecycle interval, stamped on its bus's clock."""
     kind: str
     req_id: int
     t_start: float
@@ -68,7 +79,10 @@ class Span:
 class EngineEvent:
     """One engine iteration: execution + per-direction transfer windows.
 
-    ``*_start`` are absolute sim-clock seconds; ``*_s`` are busy durations.
+    ``*_start`` are absolute seconds on the bus's clock (``clock``); ``*_s``
+    are busy durations. On the host clock the windows are the iteration's
+    host spans, and the cost model's ``overlap_s``/``stall_s``/
+    ``plan_hidden_s`` have no counterpart and read 0.
     ``overlap_s`` is the transfer-under-compute overlap the engine credited
     this iteration (matching ``EngineStats.overlap_ms`` accounting, minus
     the pipelined plan-hiding component recorded separately in
@@ -97,14 +111,119 @@ class EngineEvent:
         return d
 
 
+# ------------------------------------------------------------- host spans
+# Fixed names: PERF.md, the benchmark's readers (chipbench/span_metrics.py)
+# and its trace reduction key on them.
+HS_HTTP_GENERATE = "superinfer.http.generate"    # parsed body -> submitted
+HS_DRIVER_CONTROL = "superinfer.driver.control"  # control queue on driver
+HS_DRIVER_DELIVER = "superinfer.driver.deliver"  # outputs -> event loop
+HS_DRIVER_WAIT = "superinfer.driver.wait"        # driver parked, no work
+HS_ENGINE_STEP = "superinfer.engine.step"        # one EngineCore.step
+HS_ENGINE_SCHEDULE = "superinfer.engine.schedule"  # policy+admission+batch
+HS_DUPLEXKV_PLAN = "superinfer.duplexkv.plan"    # DuplexKV.plan_iteration
+HS_RUNNER_EXECUTE = "superinfer.runner.execute"  # executor.execute(...)
+HS_ENGINE_COMMIT = "superinfer.engine.commit"    # token emission + record
+HS_KV_D2H = "superinfer.kvstore.d2h"             # PagedKVStore.run_d2h
+HS_KV_D2H_READBACK = "superinfer.kvstore.d2h_readback"  # blocking readback
+HS_KV_H2D = "superinfer.kvstore.h2d"             # PagedKVStore.run_h2d
+HS_KV_H2D_STAGE = "superinfer.kvstore.h2d_stage"  # host stack/pad of rows
+HS_RUNNER_PREPARE = "superinfer.runner.prepare"  # block tables, padding
+HS_RUNNER_LAUNCH = "superinfer.runner.launch"    # the jitted call
+HS_RUNNER_SYNC = "superinfer.runner.sync"        # waiting on the device
+
+HOST_SPANS = (HS_HTTP_GENERATE, HS_DRIVER_CONTROL, HS_DRIVER_DELIVER,
+              HS_DRIVER_WAIT, HS_ENGINE_STEP, HS_ENGINE_SCHEDULE,
+              HS_DUPLEXKV_PLAN, HS_RUNNER_EXECUTE, HS_ENGINE_COMMIT,
+              HS_KV_D2H, HS_KV_D2H_READBACK, HS_KV_H2D, HS_KV_H2D_STAGE,
+              HS_RUNNER_PREPARE, HS_RUNNER_LAUNCH, HS_RUNNER_SYNC)
+
+# queue wait (admit - recv, host clock) histogram edges, seconds
+QUEUE_WAIT_EDGES_S = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                      0.5, 1.0, 2.5, 5.0, 10.0)
+_QUEUE_WAIT_EDGES_NS = tuple(int(e * 1e9) for e in QUEUE_WAIT_EDGES_S)
+
+# the innermost open host span of this thread (or asyncio task): a span's
+# parent is charged its duration, which is how ``self_ns`` excludes
+# children
+_OPEN_SPAN: contextvars.ContextVar = contextvars.ContextVar(
+    "superinfer_open_host_span", default=None)
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where this process has imported JAX
+    already (the simulator never does, and importing it costs seconds)."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+class HostSpan:
+    """One named host span: ``time.perf_counter_ns`` on enter and exit,
+    added to its bus's per-name counters on exit. Inside, a
+    ``TraceAnnotation`` of the same name (no kwargs, so the event name is
+    exact) puts the span in a running profiler session's trace."""
+    __slots__ = ("_bus", "name", "t0_ns", "_ann", "_parent", "_token",
+                 "_child_ns")
+
+    def __init__(self, bus: "TelemetryBus", name: str, ann):
+        self._bus = bus
+        self.name = name
+        self._ann = ann
+
+    def __enter__(self) -> "HostSpan":
+        self._parent = _OPEN_SPAN.get()
+        self._token = _OPEN_SPAN.set(self)
+        self._child_ns = 0
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _OPEN_SPAN.reset(self._token)
+        dur = t1 - self.t0_ns
+        if self._parent is not None:
+            self._parent._child_ns += dur
+        self._bus._close_span(self.name, self.t0_ns, t1, dur - self._child_ns)
+        return False
+
+
+class _NullSpan:
+    """The one shared span of a core without a bus: no clock read."""
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NULL_SPAN = _NullSpan()
+
+
+def host_span(bus: Optional["TelemetryBus"], name: str):
+    """``bus.span(name)``, or the shared null context when there is no bus
+    (``telemetry=False``): no clock read, no allocation."""
+    return NULL_SPAN if bus is None else bus.span(name)
+
+
 class TelemetryBus:
-    """Bounded ring buffers of spans and engine events for ONE replica.
+    """Bounded ring buffers of spans and engine events for ONE replica,
+    plus its host-span counters.
 
     Overflow drops the oldest entry (``deque(maxlen=...)``) and counts it,
     so a long run degrades to "most recent window" instead of growing
     without bound. Recording is append-only float/dict work — no engine
     state is read back, which is what keeps telemetry-ON runs
-    timing-identical (the sim clock never sees the bus).
+    timing-identical (the sim clock never sees the bus). ``clock`` names
+    what ``Span``/``EngineEvent`` stamps are: "sim" seconds, or "host"
+    ``time.perf_counter`` seconds (set by the engine when its executor runs
+    the work on this host).
     """
 
     def __init__(self, capacity: int = 65536, replica: int = 0,
@@ -112,16 +231,84 @@ class TelemetryBus:
         self.capacity = int(capacity)
         self.replica = int(replica)
         self.role = role
+        self.clock = "sim"
         self.spans: deque = deque(maxlen=self.capacity)
         self.events: deque = deque(maxlen=self.capacity)
         self.spans_dropped = 0
         self.events_dropped = 0
         self.spans_recorded = 0
         self.events_recorded = 0
+        # host spans close on the HTTP event loop and on the engine driver
+        # thread: one lock over the counters below
+        self._lock = threading.Lock()
+        self._host: Dict[str, List[int]] = {}   # name -> [calls, ns, self]
+        self._iter: Dict[str, List[int]] = {}   # name -> [t0, t1, ns]
+        self._qw_count = 0
+        self._qw_total_ns = 0
+        self._qw_buckets = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
+        self._annotation = None
+
+    # -- host spans -----------------------------------------------------------
+    def span(self, name: str) -> HostSpan:
+        """Context manager timing one named host span (``HOST_SPANS``)."""
+        ann = self._annotation
+        if ann is None:
+            ann = self._annotation = _trace_annotation()
+        return HostSpan(self, name, ann(name) if ann is not None else None)
+
+    def _close_span(self, name: str, t0: int, t1: int, self_ns: int) -> None:
+        dur = t1 - t0
+        with self._lock:
+            c = self._host.get(name)
+            if c is None:
+                self._host[name] = [1, dur, self_ns]
+            else:
+                c[0] += 1
+                c[1] += dur
+                c[2] += self_ns
+            w = self._iter.get(name)
+            if w is None:
+                self._iter[name] = [t0, t1, dur]
+            else:
+                w[1] = t1
+                w[2] += dur
+
+    def begin_iteration(self) -> None:
+        """Start collecting one engine iteration's span windows."""
+        with self._lock:
+            self._iter = {}
+
+    def iteration_windows(self) -> Dict[str, Tuple[int, int, int]]:
+        """``{name: (first start_ns, last end_ns, summed ns)}`` over the
+        spans closed since ``begin_iteration``."""
+        with self._lock:
+            return {k: (v[0], v[1], v[2]) for k, v in self._iter.items()}
+
+    def count_queue_wait(self, wait_ns: int) -> None:
+        """One request's host-clock queue wait (admit - recv)."""
+        with self._lock:
+            self._qw_count += 1
+            self._qw_total_ns += wait_ns
+            self._qw_buckets[bisect.bisect_left(_QUEUE_WAIT_EDGES_NS,
+                                                wait_ns)] += 1
+
+    def host_counters(self) -> Dict[str, Any]:
+        """Plain-dict snapshot of the host-span counters and the queue-wait
+        histogram (``buckets[i]`` counts waits <= ``le_s[i]``, the last
+        one the rest)."""
+        with self._lock:
+            return dict(
+                clock=self.clock,
+                spans={k: dict(calls=c, total_ns=t, self_ns=s)
+                       for k, (c, t, s) in self._host.items()},
+                queue_wait=dict(count=self._qw_count,
+                                total_ns=self._qw_total_ns,
+                                le_s=list(QUEUE_WAIT_EDGES_S),
+                                buckets=list(self._qw_buckets)))
 
     # -- recording ----------------------------------------------------------
-    def span(self, kind: str, req_id: int, t_start: float, t_end: float,
-             slo_class: str = "standard", **attrs) -> None:
+    def record(self, kind: str, req_id: int, t_start: float, t_end: float,
+               slo_class: str = "standard", **attrs) -> None:
         if len(self.spans) == self.capacity:
             self.spans_dropped += 1
         self.spans_recorded += 1
@@ -145,7 +332,7 @@ class TelemetryBus:
 
     def snapshot(self) -> Dict[str, Any]:
         return dict(replica=self.replica, role=self.role,
-                    counters=self.counters(),
+                    counters=self.counters(), host=self.host_counters(),
                     spans=[s.row() for s in self.spans],
                     events=[e.row() for e in self.events])
 
@@ -252,17 +439,30 @@ class _Writer:
 
     def histogram(self, name: str, values: Sequence[float],
                   buckets: Sequence[float], help_: str, **labels) -> None:
-        self.header(name, "histogram", help_)
         svals = sorted(values)
-        i = 0
-        for edge in list(buckets) + [float("inf")]:
-            while i < len(svals) and svals[i] <= edge:
-                i += 1
+        counts, i = [], 0
+        for edge in buckets:
+            j = bisect.bisect_right(svals, edge)
+            counts.append(j - i)
+            i = j
+        counts.append(len(svals) - i)
+        self.histogram_counts(name, buckets, counts, float(sum(values)),
+                              help_, **labels)
+
+    def histogram_counts(self, name: str, buckets: Sequence[float],
+                         counts: Sequence[int], total: float, help_: str,
+                         **labels) -> None:
+        """A histogram already bucketed: ``counts[i]`` observations in
+        (``buckets[i-1]``, ``buckets[i]``], the last past every edge."""
+        self.header(name, "histogram", help_)
+        cum = 0
+        for edge, n in zip(list(buckets) + [float("inf")], counts):
+            cum += n
             lb = dict(labels)
             lb["le"] = "+Inf" if edge == float("inf") else _fmt(edge)
-            self.sample(name + "_bucket", i, family=name, **lb)
-        self.sample(name + "_sum", float(sum(values)), family=name, **labels)
-        self.sample(name + "_count", len(values), family=name, **labels)
+            self.sample(name + "_bucket", cum, family=name, **lb)
+        self.sample(name + "_sum", float(total), family=name, **labels)
+        self.sample(name + "_count", cum, family=name, **labels)
 
     def text(self) -> str:
         lines: List[str] = []
@@ -402,12 +602,29 @@ def render_prometheus(cores: Sequence, extra: Optional[Mapping[str, Any]]
         if bus is not None:
             iters = [e.t_end - e.t_start for e in bus.events]
             w.histogram(f"{_NS}_iteration_seconds", iters, _ITER_BUCKETS,
-                        "Engine iteration wall (sim seconds), from the "
-                        "telemetry ring (bounded window).", replica=rep)
+                        "Engine iteration wall (seconds on the recorder's "
+                        "clock: sim, or host on the paged runner), from "
+                        "the telemetry ring (bounded window).", replica=rep)
             for k, v in bus.counters().items():
                 w.header(f"{_NS}_telemetry_{k}", "counter",
                          "Telemetry ring-buffer accounting.")
                 w.sample(f"{_NS}_telemetry_{k}", v, replica=rep)
+            host = bus.host_counters()
+            w.header(f"{_NS}_host_span_seconds_total", "counter",
+                     "Host-clock seconds inside each named host span.")
+            w.header(f"{_NS}_host_span_calls_total", "counter",
+                     "Calls of each named host span.")
+            for name, c in sorted(host["spans"].items()):
+                w.sample(f"{_NS}_host_span_seconds_total",
+                         c["total_ns"] * 1e-9, replica=rep, span=name)
+                w.sample(f"{_NS}_host_span_calls_total", c["calls"],
+                         replica=rep, span=name)
+            qw = host["queue_wait"]
+            w.histogram_counts(
+                f"{_NS}_queue_wait_seconds", qw["le_s"], qw["buckets"],
+                qw["total_ns"] * 1e-9,
+                "Request queue wait on the host clock: receipt by the "
+                "front door to admission.", replica=rep)
     for k, v in dict(extra or {}).items():
         name = f"{_NS}_server_{k}"
         w.header(name, "gauge", f"Server-level metric {k}.")

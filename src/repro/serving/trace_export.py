@@ -12,11 +12,30 @@ chrome://tracing):
 * one track per request (``tid`` = 16 + req_id) carrying its lifecycle
   spans (ADMIT → PREFILL → DECODE/ROTATE_* → FINISH instant).
 
-Timestamps are SIM-CLOCK microseconds (the engine's float seconds
-* 1e6) — the same clock the SLO report is computed on. ``analyze_trace``
-recomputes channel overlap geometrically from the exported slices so
-tests and CI can assert the trace agrees with the engine's own
-``overlap_ms`` accounting.
+Timestamps are microseconds of the recorder's clock, which
+``otherData.clock`` names:
+
+* ``"sim-seconds*1e6"`` — the simulator's path: the engine's float
+  seconds, the same clock the SLO report is computed on, so the trace is
+  exact and replay-inert;
+* ``"host-perf_counter-seconds*1e6"`` — the real path (an executor that
+  runs the work on this host, the paged runner): ``time.perf_counter``
+  seconds. Iteration slices are the iteration's host spans
+  (``superinfer.engine.schedule``, ``superinfer.runner.execute``,
+  ``superinfer.kvstore.d2h``/``h2d``), lifecycle spans take the requests'
+  host stamps (receipt, admission, first token), and the cost model's
+  overlap/stall fields read 0.
+
+How a host span lines up with the device trace: every host span also
+opens a ``jax.profiler.TraceAnnotation`` of the same name, so in a
+profiler session it lands in the ``.xplane.pb`` on the device ops' own
+timeline. ``time.perf_counter`` (CLOCK_MONOTONIC) differs from that
+timeline by one constant offset, which any span present in both (one
+``superinfer.engine.step``) gives.
+
+``analyze_trace`` recomputes channel overlap geometrically from the
+exported slices so tests and CI can assert the trace agrees with the
+engine's own ``overlap_ms`` accounting (sim clock).
 """
 import json
 from typing import Any, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
@@ -30,7 +49,9 @@ REQ_TRACK_BASE = 16     # request lifecycle tracks start here (16 + req_id)
 _TRACK_NAMES = {TRACK_SCHED: "scheduler", TRACK_COMPUTE: "compute",
                 TRACK_D2H: "D2H", TRACK_H2D: "H2D"}
 
-_US = 1e6               # sim seconds -> trace microseconds
+_US = 1e6               # recorder seconds -> trace microseconds
+CLOCK_NAMES = {"sim": "sim-seconds*1e6",
+               "host": "host-perf_counter-seconds*1e6"}
 
 
 def _meta(pid: int, tid: Optional[int], name: str, what: str) -> Dict:
@@ -110,11 +131,13 @@ def trace_events(buses: Iterable) -> List[Dict]:
 def export_trace(buses: Iterable) -> Dict[str, Any]:
     """Assemble the full Chrome-trace document from telemetry buses."""
     buses = list(buses)
+    # the replicas of one engine share one executor kind, so one clock
+    clock = buses[0].clock if buses else "sim"
     return {
         "traceEvents": trace_events(buses),
         "displayTimeUnit": "ms",
         "otherData": {
-            "clock": "sim-seconds*1e6",
+            "clock": CLOCK_NAMES[clock],
             "replicas": len(buses),
             "counters": {str(b.replica): b.counters() for b in buses},
         },

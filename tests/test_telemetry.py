@@ -116,7 +116,7 @@ def test_lifecycle_spans_cover_every_request():
 def test_ring_buffer_drops_oldest_and_counts():
     bus = TelemetryBus(capacity=4)
     for i in range(10):
-        bus.span("ADMIT", req_id=i, t_start=float(i), t_end=float(i))
+        bus.record("ADMIT", req_id=i, t_start=float(i), t_end=float(i))
     assert len(list(bus.spans)) == 4
     assert [s.req_id for s in bus.spans] == [6, 7, 8, 9]
     assert bus.counters()["spans_dropped"] == 6
