@@ -356,6 +356,8 @@ def main(argv=None):
             decode_batches=sum(e.decode_batches for e in execs),
             decode_tokens=sum(e.decode_tokens for e in execs),
             attn_launches=sum(e.attn_launches for e in execs),
+            attn_block_slots=sum(e.attn_block_slots for e in execs),
+            attn_blocks_live=sum(e.attn_blocks_live for e in execs),
             kv_copy_launches=sum(e.store.copy_launches for e in execs),
             kv_rows_moved=sum(e.store.d2h_rows + e.store.h2d_rows
                               + e.store.d2d_rows for e in execs))

@@ -515,6 +515,11 @@ class PagedModelRunner(Executor):
         self.decode_batches = 0
         self.decode_tokens = 0
         self.attn_launches = 0
+        # block slots of the padded (batch, table) grid vs blocks the real
+        # contexts occupy, over every layer: 1 - live/slots is the share
+        # of the padded walk the attention kernel skips
+        self.attn_block_slots = 0
+        self.attn_blocks_live = 0
         self.prefill_chunks_run = 0
 
     # ------------------------------------------------------------- binding
@@ -698,6 +703,9 @@ class PagedModelRunner(Executor):
         self.decode_batches += 1
         self.decode_tokens += len(dec)
         self.attn_launches += len(self._layers)
+        self.attn_block_slots += bp * mbp * len(self._layers)
+        self.attn_blocks_live += sum(_cdiv(cl + 1, P)
+                                     for cl in cls) * len(self._layers)
         if defer:
             return nxt                          # device array, no host sync
         with host_span(self.telemetry, HS_RUNNER_SYNC):

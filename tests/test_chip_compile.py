@@ -69,13 +69,14 @@ def _compiled_text(fn, *args, **jit_kw):
     return jax.jit(fn, **jit_kw).lower(*args).compile().as_text()
 
 
+@pytest.mark.parametrize("mb", [MB, 2 * MB])     # 4096 and 8192 tokens
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_paged_attention_compiles(cfg, one_chip, kv_dtype):
+def test_paged_attention_compiles(cfg, one_chip, kv_dtype, mb):
     from repro.kernels.paged_attention import paged_attention_tpu
     dtype = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
     pool, scales = _pool_shapes(cfg, one_chip, dtype)
     q = _struct((B, cfg.num_heads, cfg.head_dim), jnp.bfloat16, one_chip)
-    bt = _struct((B, MB), jnp.int32, one_chip)
+    bt = _struct((B, mb), jnp.int32, one_chip)
     cl = _struct((B,), jnp.int32, one_chip)
 
     def attend(q, pool, bt, cl, scales=None):

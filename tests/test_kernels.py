@@ -37,21 +37,41 @@ def test_flash_attention_sweep(B, Sq, Skv, H, D, causal, window, dtype):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,Hkv,D,P,NB,MB", [
-    (2, 8, 2, 32, 8, 16, 4),
-    (3, 4, 4, 16, 16, 32, 3),     # MHA
-    (1, 16, 2, 64, 8, 12, 6),
-    (4, 8, 1, 32, 16, 24, 2),     # MQA
+@pytest.mark.parametrize("B,H,Hkv,D,P,NB,MB,cls", [
+    (2, 8, 2, 32, 8, 16, 4, None),
+    (3, 4, 4, 16, 16, 32, 3, None),     # MHA
+    (1, 16, 2, 64, 8, 12, 6, None),
+    (4, 8, 1, 32, 16, 24, 2, None),     # MQA
+    # tiles of 4 blocks over a 6-wide table: contexts end mid-tile and
+    # mid-block, one lane fills exactly one block
+    (3, 8, 2, 32, 8, 40, 6, [45, 8, 17]),
+    (4, 4, 2, 16, 8, 24, 4, [1, 30, 1, 9]),     # lanes with context 1
+    (2, 4, 1, 16, 16, 8, 3, [48, 20]),   # MB < the byte budget's n, odd MB
+    (2, 8, 4, 16, 8, 6, 1, [5, 8]),      # one-entry table
 ])
-def test_paged_attention_sweep(B, H, Hkv, D, P, NB, MB, dtype):
+def test_paged_attention_sweep(B, H, Hkv, D, P, NB, MB, cls, dtype):
     q = jnp.asarray(RNG.standard_normal((B, H, D)), dtype)
     pool = jnp.asarray(RNG.standard_normal((NB, 2, P, Hkv, D)), dtype)
     bt = jnp.asarray(RNG.permutation(NB)[:B * MB].reshape(B, MB), jnp.int32)
-    cl = jnp.asarray(RNG.integers(1, MB * P + 1, B), jnp.int32)
+    cl = jnp.asarray(RNG.integers(1, MB * P + 1, B) if cls is None else cls,
+                     jnp.int32)
     out = paged_attention_tpu(q, pool, bt, cl)
     want = ref.paged_attention_ref(q, pool, bt, cl)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("MB,block_bytes,n", [
+    (256, 65536, 16),        # qwen2.5-32b / yi-34b bf16 rows of one layer
+    (512, 32768, 32),        # the same rows in int8
+    (6, 64, 4),              # bounded by the table: the largest pow2 <= MB
+    (3, 64, 2),
+    (1, 64, 1),
+    (8, 1 << 21, 1),         # one block past the budget still gets a tile
+])
+def test_blocks_per_tile(MB, block_bytes, n):
+    from repro.kernels.paged_attention import blocks_per_tile
+    assert blocks_per_tile(MB, block_bytes) == n
 
 
 def test_paged_attention_matches_dense_flash():
@@ -82,7 +102,9 @@ def test_paged_attention_matches_dense_flash():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want2), atol=1e-5)
 
 
-def test_paged_attention_layered_pool():
+@pytest.mark.parametrize("cls", [None, [23, 1], [17, 24]],
+                         ids=["random", "mid_tile_and_context_1", "mid_tile"])
+def test_paged_attention_layered_pool(cls):
     """layer= addresses a (NB, L, 2, P, Hkv, D) multi-layer pool: each layer
     slice must match the flat-pool kernel on that slice."""
     B, H, Hkv, D, P, NB, MB, L = 2, 4, 2, 16, 8, 12, 3, 3
@@ -90,12 +112,44 @@ def test_paged_attention_layered_pool():
     pool = jnp.asarray(RNG.standard_normal((NB, L, 2, P, Hkv, D)),
                        jnp.float32)
     bt = jnp.asarray(RNG.permutation(NB)[:B * MB].reshape(B, MB), jnp.int32)
-    cl = jnp.asarray(RNG.integers(1, MB * P + 1, B), jnp.int32)
+    cl = jnp.asarray(RNG.integers(1, MB * P + 1, B) if cls is None else cls,
+                     jnp.int32)
     for l in range(L):
         out = paged_attention_tpu(q, pool, bt, cl, layer=l)
         want = ref.paged_attention_ref(q, pool[:, l], bt, cl)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+
+def test_paged_attention_never_reads_past_the_context():
+    """Poison: the trash row and every block-table slot past a lane's
+    context hold NaN (as can a stale row), and a lane with context 0 reads
+    nothing. Each real lane must still equal the reference over a clean
+    pool, so no padded slot was read (0 * NaN in p @ V would show)."""
+    B, H, Hkv, D, P, L, MB = 4, 8, 2, 16, 8, 2, 6
+    lens = [45, 1, 9, 0]                 # mid-tile, one token, one block + 1
+    NB = B * MB + 2
+    trash = NB - 1
+    q = jnp.asarray(RNG.standard_normal((B, H, D)), jnp.float32)
+    clean = RNG.standard_normal((NB, L, 2, P, Hkv, D)).astype(np.float32)
+    rows = RNG.permutation(NB - 1)[:B * MB].reshape(B, MB)
+    bt = np.full((B, MB), trash, np.int32)
+    poison = clean.copy()
+    poison[trash] = np.nan
+    for b, c in enumerate(lens):
+        live = -(-c // P)
+        bt[b, :live] = rows[b, :live]
+        poison[rows[b, live:]] = np.nan      # rows only slots past c name
+        bt[b, live:live + 2] = rows[b, live:live + 2]
+    cl = jnp.asarray(lens, jnp.int32)
+    ref_bt = np.where(np.isnan(poison[bt][..., 0, 0, 0, 0, 0]), 0, bt)
+    for l in range(L):
+        out = np.asarray(paged_attention_tpu(q, jnp.asarray(poison),
+                                             jnp.asarray(bt), cl, layer=l))
+        want = np.asarray(ref.paged_attention_ref(
+            q, jnp.asarray(clean[:, l]), jnp.asarray(ref_bt), cl))
+        np.testing.assert_allclose(out[:3], want[:3], atol=2e-5, rtol=2e-5)
+        assert np.all(out[3] == 0.0)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])

@@ -156,30 +156,42 @@ def test_prefill_chunk_duplicate_rows_consistent():
 
 # -------------------------------------------------- fused-dequant attention
 
-def test_paged_attention_fused_dequant_matches_dequantized_pool():
+@pytest.mark.parametrize("MB,P,NB,lens", [
+    (2, 4, 8, None),
+    (6, 4, 20, [21, 4, 13]),      # tiles of 4 over 6 slots, mid-tile ends
+    (4, 4, 14, [1, 16, 1]),       # lanes with context 1, one full table
+    (3, 8, 10, [24, 9, 2]),       # MB < the byte budget's n, odd MB
+])
+def test_paged_attention_fused_dequant_matches_dequantized_pool(MB, P, NB,
+                                                               lens):
     """The in-kernel dequant must be numerically the same computation as
     running the bf16 kernel over an explicitly dequantized pool — and close
     to the unquantized original within the roundtrip error."""
     import jax.numpy as jnp
     from repro.kernels.paged_attention import paged_attention_tpu
     from repro.kernels.quant import dequantize_kv, quantize_kv
+    from repro.kernels.ref import paged_attention_ref
     rng = np.random.default_rng(SEED)
-    B, H, Hkv, D, P, L, NB, MB = 3, 4, 2, 8, 4, 2, 8, 2
+    B, H, Hkv, D, L = 3, 4, 2, 8, 2
     q = rng.standard_normal((B, H, D)).astype(np.float32)
     pool_f = rng.standard_normal((NB, L, 2, P, Hkv, D)).astype(np.float32)
     qpool, scales = quantize_kv(jnp.asarray(pool_f))
     bt = jnp.asarray(rng.permutation(NB)[:B * MB].reshape(B, MB)
                      .astype(np.int32))
-    cl = jnp.asarray(rng.integers(1, MB * P + 1, B).astype(np.int32))
+    cl = jnp.asarray(rng.integers(1, MB * P + 1, B).astype(np.int32)
+                     if lens is None else np.asarray(lens, np.int32))
+    deq = dequantize_kv(qpool, scales)
     for layer in range(L):
         fused = paged_attention_tpu(jnp.asarray(q), qpool, bt, cl,
                                     layer=layer, kv_scales=scales)
-        explicit = paged_attention_tpu(
-            jnp.asarray(q), dequantize_kv(qpool, scales), bt, cl,
-            layer=layer)
+        explicit = paged_attention_tpu(jnp.asarray(q), deq, bt, cl,
+                                       layer=layer)
         ref = paged_attention_tpu(jnp.asarray(q), jnp.asarray(pool_f), bt,
                                   cl, layer=layer)
+        oracle = paged_attention_ref(jnp.asarray(q), deq[:, layer], bt, cl)
         assert np.allclose(np.asarray(fused), np.asarray(explicit),
+                           atol=1e-5, rtol=1e-5)
+        assert np.allclose(np.asarray(fused), np.asarray(oracle),
                            atol=1e-5, rtol=1e-5)
         err = np.abs(np.asarray(fused) - np.asarray(ref)).max()
         assert err < 0.05, f"layer {layer}: fused-dequant error {err}"

@@ -103,19 +103,42 @@ def test_paged_prefix_cache_with_rotation(legacy_streams):
     assert streams == legacy_streams["shared"]
 
 
-def test_decode_is_single_batched_launch():
-    """N concurrent decodes must execute as one batched kernel invocation
-    per layer per iteration — launch count scales with iterations, never
-    with batch size (the legacy path pays N model calls per iteration)."""
+@pytest.fixture(scope="module")
+def batched_decode_run():
+    """Five requests that arrive together and decode as one batch."""
     sv = serving(4096, paged=True)
     eng = ServingEngine(CFG, sv, GH200, runner_cfg=CFG, runner_seed=SEED)
     for r in make_requests(5, seed=9):
         r.arrival_time = 0.0               # all decode together
         eng.add_request(r)
     eng.drain(max_time_s=500)
-    ex = eng.core.executor
+    return eng
+
+
+def test_decode_is_single_batched_launch(batched_decode_run):
+    """N concurrent decodes must execute as one batched kernel invocation
+    per layer per iteration — launch count scales with iterations, never
+    with batch size (the legacy path pays N model calls per iteration)."""
+    ex = batched_decode_run.core.executor
     assert ex.decode_tokens > ex.decode_batches        # real batching
     assert ex.attn_launches == ex.decode_batches * len(ex._layers)
+
+
+def test_attention_block_counters(batched_decode_run):
+    """attn_blocks_live counts the blocks the real contexts occupy, layer by
+    layer: a request that generated G tokens ran G - 1 decode steps, at
+    contexts prompt_len + 1 .. prompt_len + G - 1. The padded grid the old
+    kernel walked (attn_block_slots) is never smaller."""
+    eng = batched_decode_run
+    ex = eng.core.executor
+    P = ex.serving.block_size
+    live = sum(-(-ctx // P)
+               for r in eng.core.submitted
+               for ctx in range(r.prompt_len + 1,
+                                r.prompt_len + len(r.generated_ids)))
+    assert live > 0
+    assert ex.attn_blocks_live == live * len(ex._layers)
+    assert ex.attn_blocks_live <= ex.attn_block_slots
 
 
 def test_flag_off_keeps_sim_executor():
