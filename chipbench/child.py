@@ -24,14 +24,22 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
+from arch import resolve                          # noqa: E402
 from common import SRC, log                       # noqa: E402
-from flops import dims, layer_matmul_params, row_bytes   # noqa: E402
 
 RESERVE_BYTES = 2 << 30      # HBM left free of the pool: activations, temps
 
 
 def emit(msg: str, **kw) -> None:
     print("@@" + json.dumps(dict(kw, msg=msg)), flush=True)
+
+
+def fill_blocks(arch, cfg, bytes_limit: int) -> int:
+    """Pool blocks that fill the chip's memory the weights and the reserve
+    leave (a ``"fill"`` pool)."""
+    m = arch.dims(cfg)
+    return int((bytes_limit - arch.weight_bytes(m) - RESERVE_BYTES)
+               // arch.row_bytes(m))
 
 
 def _pow2(n: int) -> int:
@@ -88,6 +96,7 @@ class Child:
         self.spec = spec
         self.meter = meter
         self.cfg = spec["config"]
+        self.arch = resolve(self.cfg)
         self.rehearse = spec["rehearse_cpu"]
         self.wcfg = dict(self.cfg, **self.cfg["rehearsal"]) if self.rehearse \
             else self.cfg
@@ -167,11 +176,8 @@ class Child:
         if s["hbm_blocks"] != "fill":
             return int(s["hbm_blocks"])
         import jax
-        m = dims(self.cfg)
-        limit = jax.devices()[0].memory_stats()["bytes_limit"]
-        weights = (m["layers"] * (layer_matmul_params(m) + 2 * m["d"])
-                   + 2 * m["v"] * m["d"] + m["d"]) * m["elt"]
-        return int((limit - weights - RESERVE_BYTES) // row_bytes(m))
+        return fill_blocks(self.arch, self.cfg,
+                           jax.devices()[0].memory_stats()["bytes_limit"])
 
     def inject(self, eng):
         """Serve the benchmark's weights: drop the ones the runner made,
@@ -226,10 +232,15 @@ class Child:
         for k in ("iterations", "active_rotations", "passive_preemptions",
                   "prefill_tokens"):
             out[k] = getattr(st, k, None)
-        for k in ("decode_tokens", "decode_batches", "prefill_chunks_run"):
+        for k in self.arch.RUNNER_COUNTERS:
             out[k] = getattr(run, k, None)
         for k in ("d2h_rows", "h2d_rows", "d2d_rows", "copy_launches"):
             out[k] = getattr(store, k, None)
+        # the program's host spans and queue wait (None where the engine
+        # has no flight recorder, or one without them)
+        tel = getattr(eng, "telemetry", None)
+        out["host"] = (tel.host_counters() if hasattr(tel, "host_counters")
+                       else None)
         return out
 
     async def run(self) -> int:
@@ -237,11 +248,9 @@ class Child:
         hbm = self.pool_blocks()
         scfg = ServerConfig(
             port=0, model=self.cfg["program_model"], hw="tpu-v5e",
-            paged_runner=True,
-            runner_layers=0 if self.rehearse else
-            int(self.cfg["num_hidden_layers"]),
-            hbm_blocks=hbm, pace=False,
-            seed=self.spec["seed"] % (1 << 31)).validate()
+            paged_runner=True, hbm_blocks=hbm, pace=False,
+            seed=self.spec["seed"] % (1 << 31),
+            **self.arch.server_kwargs(self.cfg, self.rehearse)).validate()
         box, ready = {}, asyncio.Event()
 
         def on_ready(server, service):
@@ -356,7 +365,8 @@ class Child:
         import trace_reduce
         t0 = time.monotonic()
         paths = sorted(Path(self.trace_dir).rglob("*.xplane.pb"))
-        summary = (trace_reduce.reduce(trace_reduce.extract(str(paths[-1])))
+        summary = (trace_reduce.reduce(trace_reduce.extract(str(paths[-1])),
+                                       kernels=self.arch.KERNELS)
                    if paths else None)
         shutil.rmtree(self.trace_dir, ignore_errors=True)
         log(f"chipbench: trace read and reduced in "
@@ -378,7 +388,8 @@ class Child:
             out.append(reference.gaps(
                 self.wcfg, layers, head, s["prompt_ids"], s["token_ids"],
                 pad_len=ref["pad_len"], n_out=ref["n_out"],
-                control=bool(self.spec.get("control"))))
+                control=bool(self.spec.get("control")),
+                forward=self.arch.logits))
         log(f"chipbench: reference over {len(samples)} requests took "
             f"{time.monotonic() - t0:.3f} s (host clock)")
         return out

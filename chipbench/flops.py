@@ -27,6 +27,13 @@ def layer_matmul_params(m: Dict[str, int]) -> int:
     return d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
 
 
+def weight_bytes(m: Dict[str, int]) -> int:
+    """The weights as served: every layer's matrices and its two norms, the
+    embedding and the untied head, and the final norm."""
+    return (m["layers"] * (layer_matmul_params(m) + 2 * m["d"])
+            + 2 * m["v"] * m["d"] + m["d"]) * m["elt"]
+
+
 def attn_flops(m: Dict[str, int], ctx: int) -> int:
     """One query over ``ctx`` keys in one layer: q.k and p.v, 2 FLOPs a MAC."""
     return 4 * ctx * m["h"] * m["hd"]

@@ -3,15 +3,16 @@ own under ``metrics/`` (found by its name in ``BENCHMARK.json``) that
 names one of these readers, so a later cell whose quantity moves another
 end-to-end metric can name the same reader under a name of its own.
 
-A reader takes the run's context (``run.context``): program counters at
-the window's edges, the trace's reduction (a chip run only, else None),
-the chip's peaks, and the client's decode contexts and first-token
-prompts inside the window. It returns None where it finds nothing to read,
-never 0 for a share of a roofline or a peak."""
+A reader takes the run's context (``run.context``): the configuration's
+architecture module (``arch``, whose work counts the readers use) and its
+sizes (``dims``), program counters at the window's edges, the trace's
+reduction (a chip run only, else None), the chip's peaks, and the client's
+decode contexts and first-token prompts inside the window. It returns None
+where it finds nothing to read, never 0 for a share of a roofline or a
+peak."""
 from __future__ import annotations
 
-from flops import (decode_attention_work, decode_token_flops, prefill_flops,
-                   roofline_seconds, row_bytes)
+from flops import roofline_seconds
 
 ROWS = ("d2h_rows", "h2d_rows", "d2d_rows")
 
@@ -73,7 +74,8 @@ def paged_attention_roofline(ctx):
     t = tr and tr["kernels"].get("paged_attention")
     if not t or not ctx["decode_ctx"]:
         return None
-    flops, nbytes = decode_attention_work(ctx["dims"], ctx["decode_ctx"])
+    flops, nbytes = ctx["arch"].decode_attention_work(ctx["dims"],
+                                                      ctx["decode_ctx"])
     return roofline_seconds(flops, nbytes, ctx["peaks"])[0] / t * 100.0
 
 
@@ -86,8 +88,18 @@ def kv_copy_roofline(ctx):
     rows = _delta(ctx, *ROWS)
     if not t or not rows:
         return None
-    nbytes = 2 * rows * row_bytes(ctx["dims"])
+    nbytes = 2 * rows * ctx["arch"].row_bytes(ctx["dims"])
     return roofline_seconds(0, nbytes, ctx["peaks"])[0] / t * 100.0
+
+
+def attn_live_share(ctx):
+    """Kernels: percent of the block slots of the padded block tables over
+    which the paged-attention kernel was launched that held a live KV block
+    (``attn_blocks_live`` / ``attn_block_slots``, every layer, over the
+    window): the work the kernel skips is the rest."""
+    live = _delta(ctx, "attn_blocks_live")
+    slots = _delta(ctx, "attn_block_slots")
+    return live / slots * 100.0 if slots else None
 
 
 def idle_share(ctx):
@@ -107,9 +119,9 @@ def step_mfu(ctx):
     tr = ctx["trace"]
     if not tr or tr["window_s"] <= 0:
         return None
-    m = ctx["dims"]
-    flops = (sum(prefill_flops(m, p) for p in ctx["first_token_prompts"])
-             + sum(decode_token_flops(m, c) for c in ctx["decode_ctx"]))
+    a, m = ctx["arch"], ctx["dims"]
+    flops = (sum(a.prefill_flops(m, p) for p in ctx["first_token_prompts"])
+             + sum(a.decode_token_flops(m, c) for c in ctx["decode_ctx"]))
     if not flops:
         return None
     return flops / (tr["window_s"] * ctx["peaks"]["bf16_flops_per_s"]) * 100

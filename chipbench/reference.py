@@ -133,10 +133,12 @@ def logits(cfg: Dict, layers, head, ids: np.ndarray, start: int, n_out: int,
 
 def gaps(cfg: Dict, layers, head, prompt: Sequence[int],
          served: Sequence[int], pad_len: int, n_out: int,
-         control: bool = False) -> Dict[str, List[float]]:
+         control: bool = False, forward=logits) -> Dict[str, List[float]]:
     """Per served token: ``served`` is the reference's gap of the token the
     server sent, and with ``control`` also ``control``, the gap of the token
-    the int8 model puts first at the same position."""
+    the int8 model puts first at the same position. ``forward`` is the
+    architecture's reference pass (``arch``'s ``logits``); this file's
+    ``logits`` is the dense decoder's."""
     p, n = len(prompt), len(served)
     seq = list(prompt) + list(served[:-1])
     if p - 1 + n_out > pad_len or n > n_out:
@@ -146,8 +148,8 @@ def gaps(cfg: Dict, layers, head, prompt: Sequence[int],
     ids[:len(seq)] = seq
     toks = np.zeros(n_out, np.int32)
     toks[:n] = served
-    ref = logits(cfg, layers, head, ids, p - 1, n_out)
-    pick = logits(cfg, layers, head, ids, p - 1, n_out, control=True) \
+    ref = forward(cfg, layers, head, ids, p - 1, n_out)
+    pick = forward(cfg, layers, head, ids, p - 1, n_out, control=True) \
         if control else ref
     g_served, g_ctrl = jax.device_get(_gaps(ref, jnp.asarray(toks), pick))
     out = dict(served=[float(x) for x in g_served[:n]])

@@ -50,8 +50,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 import client                                    # noqa: E402
 import stats                                     # noqa: E402
 import traffic as traffic_gen                    # noqa: E402
+from arch import resolve                         # noqa: E402
 from common import BENCH_DIR, ROOT, log, peaks, resolve_cell   # noqa: E402
-from flops import dims                           # noqa: E402
 
 CHILD_START_TIMEOUT_S = 900.0     # first run of a cell compiles
 WARMUP_FIRST_TOKEN_TIMEOUT_S = 300.0
@@ -297,10 +297,11 @@ def report(args, cell, device, out) -> Dict[str, Any]:
 
 
 def context(args, cell, device, out) -> Dict[str, Any]:
-    """What the per-layer readers read: program counters at the window's
-    edges, the trace's reduction (a chip run only), and the client's token
-    counts and context lengths inside the window."""
-    m = dims(out["wcfg"])
+    """What the per-layer readers read: the configuration's architecture
+    module (``arch``) and its sizes (``dims``), program counters at the
+    window's edges, the trace's reduction (a chip run only), and the
+    client's token counts and context lengths inside the window."""
+    arch = resolve(out["wcfg"])
     w0, w1 = out["w0"], out["w1"]
     decode_ctx, prompts = [], []
     for s in out["all"]:
@@ -313,7 +314,8 @@ def context(args, cell, device, out) -> Dict[str, Any]:
                     prompts.append(len(s.prompt_ids))
                 j += 1
     on_chip = device["platform"] == "tpu"
-    return dict(dims=m, c0=out["m0"], c1=out["m1"],
+    return dict(arch=arch, dims=arch.dims(out["wcfg"]),
+                c0=out["m0"], c1=out["m1"],
                 window_s=out["m1"]["t"] - out["m0"]["t"],
                 trace=out["res"].get("trace") if on_chip else None,
                 peaks=peaks(device["kind"]) if on_chip else None,
@@ -337,6 +339,7 @@ def main(argv=None) -> int:
                     help="offer this rate instead of the mix's (a sweep)")
     args = ap.parse_args(argv)
     cell = resolve_cell(args.workload)
+    resolve(cell["config"])          # an unknown model_type: no child, no run
     t_spawn = time.monotonic()
     spec = dict(config=cell["config"], settings=cell["settings"],
                 seed=args.seed, trace=args.trace,

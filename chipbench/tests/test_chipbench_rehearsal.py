@@ -61,7 +61,12 @@ def test_altered_tokens_are_caught():
     # counters only: no device metric from a CPU run
     assert set(line["metrics"]) == {"engine.iteration_ms",
                                     "engine.rotations_per_s",
-                                    "duplexkv.rows_moved_per_s"}
+                                    "duplexkv.rows_moved_per_s",
+                                    "frontdoor.queue_wait_ms",
+                                    "engine.host_ms",
+                                    "duplexkv.d2h_wait_ms",
+                                    "runner.host_ms",
+                                    "kernels.attn_live_share"}
 
 
 def test_no_chip_no_result():

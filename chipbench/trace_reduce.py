@@ -88,10 +88,13 @@ def _match(table: Dict[str, str], name: str) -> Optional[str]:
     return next((k for k, sub in table.items() if sub in name), None)
 
 
-def reduce(ev: Dict[str, list], top: int = 10) -> Dict[str, object]:
+def reduce(ev: Dict[str, list], top: int = 10,
+           kernels: Optional[Dict[str, str]] = None) -> Dict[str, object]:
     """Busy and idle time, program and kernel time, inside the window the
     ``chipbench.window`` host span marks (the whole trace if it has none).
-    Times in seconds; ``busy_s`` is averaged over the devices that ran."""
+    Times in seconds; ``busy_s`` is averaged over the devices that ran.
+    ``kernels`` is an architecture's own table (``arch``), matched before
+    ``KERNELS``."""
     win = [s for s in ev["spans"] if s[0] == WINDOW_SPAN]
     if win:
         lo, hi = win[0][1], win[0][1] + win[0][2]
@@ -99,7 +102,7 @@ def reduce(ev: Dict[str, list], top: int = 10) -> Dict[str, object]:
         pts = [(o[1], o[1] + o[2]) for o in ev["ops"]]
         lo, hi = min(p[0] for p in pts), max(p[1] for p in pts)
     devices = sorted({o[3] for o in ev["ops"]})
-    busy_ns, by_op, kernels = 0.0, {}, {}
+    busy_ns, by_op, kernel_ns = 0.0, {}, {}
     gaps: List[Tuple[float, float]] = []
     for dev in devices:
         iv = [c for o in ev["ops"] if o[3] == dev
@@ -120,9 +123,9 @@ def reduce(ev: Dict[str, list], top: int = 10) -> Dict[str, object]:
         dur = c[1] - c[0]
         by_op[o[0]] = by_op.get(o[0], 0.0) + dur
         if o[0].endswith(" [kernel]"):
-            k = _match(KERNELS, o[0])
+            k = _match(kernels or {}, o[0]) or _match(KERNELS, o[0])
             if k:
-                kernels[k] = kernels.get(k, 0.0) + dur
+                kernel_ns[k] = kernel_ns.get(k, 0.0) + dur
     programs: Dict[str, Dict[str, float]] = {}
     for m in ev["modules"]:
         c = _clip(m[1], m[1] + m[2], lo, hi)
@@ -138,7 +141,7 @@ def reduce(ev: Dict[str, list], top: int = 10) -> Dict[str, object]:
         window_s=(hi - lo) * 1e-9,
         busy_s=busy_ns / n_dev * 1e-9,
         programs=programs,
-        kernels={k: v * 1e-9 for k, v in kernels.items()},
+        kernels={k: v * 1e-9 for k, v in kernel_ns.items()},
         device_ops=[[k, v * 1e-9] for k, v in
                     sorted(by_op.items(), key=lambda kv: -kv[1])[:top]],
         idle_gaps=[[k, v] for k, v in

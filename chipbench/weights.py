@@ -3,7 +3,9 @@ in the dtype they are served in. The benchmark makes them (not the program)
 so that the reference can make the same ones again without taking anything
 the program produced.
 
-Layout: ``layers`` is a list of per-layer dicts and ``head`` a dict, keyed
+Shapes and spreads come from the configuration's architecture module
+(``arch``); ``shapes`` and ``std`` below are the dense decoder's. Its
+layout: ``layers`` is a list of per-layer dicts and ``head`` a dict, keyed
 as the paged runner keys its executed weights. Norm weights are stored as
 offsets from one (a norm's published weight is ``1 + ln``), which is how
 the runner parametrises them. Qwen2's q/k/v biases are zero: the runner
@@ -16,6 +18,7 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from arch import resolve
 from flops import dims
 
 NORM_STD = 0.1       # spread of the norm weights around one
@@ -37,7 +40,7 @@ def shapes(cfg: Dict) -> Tuple[List[Dict[str, tuple]], Dict[str, tuple]]:
     return [layer] * m["layers"], head
 
 
-def _std(name: str, shape: tuple) -> float:
+def std(name: str, shape: tuple) -> float:
     """Scales that keep activations near unit size: embeddings unit normal,
     projections 1/sqrt(fan-in), norms around one."""
     if name in ("ln1", "ln2", "final_norm"):
@@ -50,10 +53,13 @@ def _std(name: str, shape: tuple) -> float:
 
 
 def make(cfg: Dict, seed: int, dtype=None):
-    """(layers, head) on the default device, in one jitted call."""
+    """(layers, head) on the default device, in one jitted call: each leaf
+    normal from its own split of the seed's key, leaves in layer order and
+    by name within a layer, then the head's."""
     dtype = dtype or (jnp.bfloat16 if cfg["torch_dtype"] == "bfloat16"
                       else jnp.float32)
-    lshapes, hshapes = shapes(cfg)
+    arch = resolve(cfg)
+    lshapes, hshapes = arch.shapes(cfg)
     names = [(i, k, s) for i, lay in enumerate(lshapes)
              for k, s in sorted(lay.items())]
     names += [(-1, k, s) for k, s in sorted(hshapes.items())]
@@ -64,7 +70,7 @@ def make(cfg: Dict, seed: int, dtype=None):
         out = []
         for k, (_, name, shape) in zip(keys, names):
             out.append((jax.random.normal(k, shape, dtype)
-                        * jnp.asarray(_std(name, shape), dtype)))
+                        * jnp.asarray(arch.std(name, shape), dtype)))
         return out
 
     leaves = build(_key(seed))
